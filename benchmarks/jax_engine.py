@@ -20,10 +20,11 @@ campaign throughput vs the host-trace JAX path) plus the waste-mean
 z-score against the NumPy engine.
 
 The devices curve (``jax_engine/devices{d}_lanes{n}``) times the sharded
-engine on 1/2/4/8 devices at a >= 10k lane count.  It runs in a child
-process with ``--xla_force_host_platform_device_count=8`` so the parent
-benchmark process keeps its real device topology; on actual accelerator
-fleets pass ``--devices`` to use the local devices directly.
+engine on 1/2/4/8 devices at a >= 10k lane count, in this process, over
+the devices it has (a host with one chip records only ``devices1``;
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives a CPU run
+eight host devices).  One process holds the chips: a child started
+after this process touched JAX could not reach them.
 
 ``jax_engine/fused_grid_cells{n}`` is the experiment-sweep acceptance
 record: the paper grid (``repro.experiments.paper_grid``, every platform
@@ -88,10 +89,6 @@ instead of interpret mode and every device is a physical chip).
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -604,32 +601,7 @@ def _run_two_level_silent(reps: int = 3) -> None:
 
 
 def _run_devices_curve(reps: int = 3) -> None:
-    """Emit the sharded-dispatch scaling records from a child process.
-
-    The device count must be fixed before jax initializes, so the curve
-    is measured under ``--xla_force_host_platform_device_count=8`` in a
-    subprocess; the parent re-emits the child's JSON records."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.jax_engine",
-         "--devices-curve-child", "--reps", str(reps)],
-        env=env, capture_output=True, text=True, timeout=1200,
-    )
-    if proc.returncode != 0:  # pragma: no cover - surfaced to the runner
-        sys.stderr.write(proc.stderr)
-        raise RuntimeError("devices-curve child failed")
-    for line in proc.stdout.splitlines():
-        if line.startswith("{"):
-            rec = json.loads(line)
-            emit(rec["name"], rec["us_per_call"], rec["derived"])
-
-
-def _devices_curve_child(reps: int) -> None:
-    """Body of the forced-8-host-device scaling measurement."""
+    """Sharded-dispatch scaling over the devices this process has."""
     import statistics
 
     import jax
@@ -653,16 +625,16 @@ def _devices_curve_child(reps: int) -> None:
     for d in counts:
         s = statistics.median(times[d])
         base = base or s
-        print(json.dumps({
-            "name": f"jax_engine/devices{d}_lanes{n}",
-            "us_per_call": round(s * 1e6 / n, 1),
-            "derived": {
+        emit(
+            f"jax_engine/devices{d}_lanes{n}",
+            round(s * 1e6 / n, 1),
+            {
                 "jax_s": round(s, 3),
                 "jax_lanes_per_s": round(n / s, 1),
                 "speedup_vs_1dev": round(base / s, 2),
                 "n_devices": d,
             },
-        }), flush=True)
+        )
 
 
 def _timed(fn) -> float:
@@ -680,14 +652,8 @@ if __name__ == "__main__":
         "--devices", default=None,
         help="shard the timed engine calls ('all', an int, default: one)",
     )
-    ap.add_argument("--devices-curve-child", action="store_true",
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--reps", type=int, default=3, help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.devices_curve_child:
-        _devices_curve_child(args.reps)
-    else:
-        devices = args.devices
-        if devices and devices != "all":
-            devices = int(devices)
-        run(quick=not args.full, devices=devices)
+    devices = args.devices
+    if devices and devices != "all":
+        devices = int(devices)
+    run(quick=not args.full, devices=devices)

@@ -90,7 +90,7 @@ class _Capture:
 # --------------------------------------------------------------------- #
 def _iter_eqns(jaxpr):
     """All equations, recursing into call/scan/while sub-jaxprs."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for eqn in jaxpr.eqns:
         yield eqn
@@ -222,10 +222,8 @@ def audit_callable(
     report = AuditReport(label=label)
     fdt, idt = np.dtype(fdt), np.dtype(idt)
     ctx = contextlib.nullcontext()
-    if fdt == np.float64 and not jax.config.jax_enable_x64:
-        from jax.experimental import enable_x64
-
-        ctx = enable_x64()
+    if fdt == np.float64:
+        ctx = jax.enable_x64(True)
     jitted = fn if hasattr(fn, "trace") else jax.jit(fn)
     with ctx:
         try:

@@ -112,6 +112,9 @@ TWIN_REGISTRY: Tuple[TwinPair, ...] = (
              "repro.kernels.sim_step", "gap_transform"),
     TwinPair("repro.core.events", "gap_transform_indexed_np",
              "repro.kernels.sim_step", "gap_transform_indexed"),
+    # the lane machine's clock tolerance (float32 clocks on the TPU)
+    TwinPair("repro.core.batch_sim", "clock_tol",
+             "repro.kernels.sim_step", "clock_tol"),
     # the differentiable analytic waste layer (branchless table models)
     TwinPair("repro.core.analytic", "precision_from_fp",
              "repro.kernels.analytic", "precision_from_fp"),

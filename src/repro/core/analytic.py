@@ -452,15 +452,7 @@ def newton_optimize_tables(
         tables_p = tables
     lo, hi0, hi1 = _newton_bounds(tables_p, alpha, capped)
 
-    if jax.config.jax_enable_x64:
-        import contextlib
-
-        ctx = contextlib.nullcontext()
-    else:
-        from jax.experimental import enable_x64
-
-        ctx = enable_x64()
-    with ctx:
+    with jax.enable_x64(True):
         dev = None
         if devices:
             dev = devices[0] if isinstance(devices, (list, tuple)) else devices

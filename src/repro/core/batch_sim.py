@@ -51,7 +51,7 @@ from typing import List, Optional, Sequence, Union
 import numpy as np
 
 from .events import BatchTraces, TraceSpec, pad_sentinel
-from .simulator import SimResult, Strategy, _EPS
+from .simulator import SimResult, Strategy, _CLOCK_ULPS, _EPS
 from .waste import Platform
 
 __all__ = ["MODE_CODES", "BatchResult", "pad_lane_axis", "simulate_batch"]
@@ -202,6 +202,15 @@ def _lane_params(work, platform, strategy, L: int):
         dtype=np.int64,
     )
     return W, C, D, R, M, T_R, T_P, mode, q, C2, R2, V, fmem, rho, kv
+
+
+# repro-twin: repro.kernels.sim_step.clock_tol
+def clock_tol(t, eps: float):
+    """Tolerance of an end-of-span check made at clock ``t``: the
+    absolute ``eps`` or ``_CLOCK_ULPS`` ulps of ``t``'s float type,
+    whichever is larger (NumPy twin of
+    :func:`repro.kernels.sim_step.clock_tol`, which says why)."""
+    return np.maximum(eps, t * (_CLOCK_ULPS * np.finfo(t.dtype).eps))
 
 
 def pad_lane_axis(a: np.ndarray, n: int, fill) -> np.ndarray:
@@ -422,7 +431,7 @@ class _BatchEngine:
                 # exactly as the scalar engine would at a period boundary
                 self.exhausted |= mn & (self.t > self.horizon)
                 remaining = wpp - self.period_work
-                ck = mn & (remaining <= _EPS)
+                ck = mn & (remaining <= clock_tol(self.t, _EPS))
                 prim[ck] = _PR_CKPT
                 cont[ck] = _C_CKPTREG
                 self.na_saved[ck] = na[ck]
@@ -501,7 +510,7 @@ class _BatchEngine:
 
             if occ[_PH_EP_WC]:
                 widx = np.flatnonzero(self.phase == _PH_EP_WC)
-                over = self.t[widx] >= self.ep_end[widx] - _EPS
+                over = self.t[widx] >= self.ep_end[widx] - clock_tol(self.t[widx], _EPS)
                 self.phase[widx[over]] = _PH_MAIN  # window exhausted
                 gidx = widx[~over]
                 if gidx.size:
@@ -599,7 +608,7 @@ class _BatchEngine:
                 cw = wok & credit
                 self.period_work[cw] += dt[cw]
                 self.t[wok] = target[wok]
-                fin = wok & (self.saved + self.unsaved >= self.W - _EPS)
+                fin = wok & (self.saved + self.unsaved >= self.W - clock_tol(self.t, _EPS))
                 self.done[fin] = True
                 self.phase[fin] = _PH_DONE
             if idlem.any():

@@ -52,14 +52,16 @@ jit-compiles to a single XLA while-loop, unlocking Monte-Carlo campaigns
   D2H copies overlap too).  State buffers are donated to the executable.
 * **Two-level compilation cache** — an in-process runner registry keyed
   on the (pallas, precision, migration, device-set) specialization, plus
-  JAX's persistent compilation cache (:func:`enable_compilation_cache`
-  or ``REPRO_JAX_CACHE_DIR``) so repeated sweep *processes* skip XLA
-  recompiles of the same chunk shapes entirely.
+  JAX's persistent compilation cache (:func:`enable_compilation_cache`:
+  ``JAX_COMPILATION_CACHE_DIR`` or ``.jax_cache/`` in the checkout) so
+  repeated sweep *processes* skip XLA recompiles of the same chunk
+  shapes entirely.
 
 Because this engine and the NumPy engine execute the same primitive
 sequence in the same order, their makespans agree to float rounding when
-run in float64 (``precision="x64"``, the default off-TPU; TPUs have no
-f64 and fall back to f32).  Trust filtering happens host-side through
+run in float64 (``precision="x64"``, the default off-TPU; on a TPU the
+engine runs float32 with Threefry-x32 draws, and its end-of-span checks
+allow a few ulps of the clock, see ``sim_step.clock_tol``).  Trust filtering happens host-side through
 the NumPy engine's own filter, so the deterministic trust settings
 ``q in {0, 1}`` used by all paper strategies are trace-identical across
 the scalar, NumPy-batch, and JAX engines.
@@ -129,7 +131,6 @@ for exactness tests and KS/accounting fidelity checks.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import warnings
 from collections import OrderedDict
@@ -158,7 +159,9 @@ __all__ = [
 ]
 
 #: host-side time split of the most recent :func:`simulate_batch_jax`
-#: call: {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks"}.
+#: call: {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks"},
+#: plus what the call resolved: ``precision`` ("x64"/"x32") and
+#: ``pallas`` ("compiled", "interpret" or "off").
 #: ``pack_s`` is host NumPy packing (events for the host trace mode,
 #: O(lanes) scalars for device mode), ``dispatch_s`` device_put + async
 #: launch, ``fetch_s`` the device wait + D2H copies.  Benchmarks read it
@@ -173,9 +176,12 @@ LANE_TILE = 1024
 #: still leaves every device a few tiles, large enough to stay tiled
 SHARD_TILE = 128
 
-#: environment knob: point it at a directory to persist compiled
-#: executables across processes (see :func:`enable_compilation_cache`)
-CACHE_ENV = "REPRO_JAX_CACHE_DIR"
+#: where compiled executables persist when ``JAX_COMPILATION_CACHE_DIR``
+#: is not set: one fixed directory in the checkout (a cache only hits
+#: from a path that does not move between processes)
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"
+))
 
 #: default chunks: bound device-resident lanes so 100k-lane grids don't
 #: OOM (and bound the inert-lane overhead of the no-repacking design).
@@ -218,9 +224,9 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
 
     from ..kernels.sim_step import (
         FLAG_CKPT_OK, FLAG_FAULTED, FLAG_FIN, FLAG_OK, FLAG_REG,
-        PRIM_WORK_NC, cell_gather, counter_uniform, counter_uniform2,
-        masked_primitive_update, primitive_update, segment_cell_sums,
-        stream_advance, stream_key, threefry2x32,
+        PRIM_WORK_NC, cell_gather, clock_tol, counter_uniform,
+        counter_uniform2, masked_primitive_update, primitive_update,
+        segment_cell_sums, stream_advance, stream_key, threefry2x32,
     )
 
     # cell multiplexing (fused sweeps): per-cell parameter tables are
@@ -279,7 +285,7 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             return stream_key(*threefry2x32(
                 consts["s0"], consts["s1"], consts["sid_lo"],
                 (consts["sid_hi"] << 4) | jnp.uint32(kind),
-            ))
+            ), x64=fdt == np.float64)
 
         fg_key = subkey(E.STREAM_FAULT_GAP)
         tc_key = subkey(E.STREAM_TP_COIN)
@@ -510,7 +516,7 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
 
         exhausted = st["exhausted"] | (mn & (t > horizon))
         remaining = wpp - period_work
-        ck = mn & (remaining <= eps)
+        ck = mn & (remaining <= clock_tol(t, eps))
         prim = jnp.where(ck, B._PR_CKPT, prim)
         cont = jnp.where(ck, B._C_CKPTREG, cont)
         na_saved = jnp.where(ck, na, na_saved)
@@ -612,7 +618,7 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
 
         def _wc(args):
             prim, target, cont, phase = args
-            over = wc & (t >= ep_end - eps)
+            over = wc & (t >= ep_end - clock_tol(t, eps))
             phase = jnp.where(over, B._PH_MAIN, phase)  # window exhausted
             g = wc & ~over
             tp = jnp.where(jnp.isnan(T_P), tp_eff_default, T_P)
@@ -1065,46 +1071,35 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
 _RUN_CACHE: "OrderedDict" = OrderedDict()
 _RUN_CACHE_MAX = 64
 
-_cache_env_done = False
+_cache_enabled = False
 
 
-def enable_compilation_cache(path: Union[str, "os.PathLike"]) -> None:
-    """Persist compiled engine executables under ``path``.
+def enable_compilation_cache() -> None:
+    """Persist compiled engine executables across processes.
 
     Repeated sweep invocations (separate processes hitting the same chunk
     shape / migration specialization) then skip XLA recompiles entirely:
     the in-process registry (``_RUN_CACHE``) already de-duplicates within
-    a process, and this extends it across processes via
-    ``jax.config.jax_compilation_cache_dir``.  Call it — or export
-    ``REPRO_JAX_CACHE_DIR`` — *before the first JAX computation* of the
-    process; JAX only picks the cache directory up at backend
-    initialization.
+    a process, and JAX's persistent compilation cache extends it across
+    processes.  The directory is ``JAX_COMPILATION_CACHE_DIR`` where that
+    is set (JAX reads it itself; no directory is set here), and
+    :data:`DEFAULT_CACHE_DIR` otherwise.  :func:`simulate_batch_jax`
+    calls this on its first use; JAX opens the cache at its first
+    compile that consults it.
     """
     import jax
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for opt, val in (
-        # the engine's executables are small and quick to build one by
-        # one but numerous (chunk shape x migration x precision), so
-        # cache everything regardless of size / compile time
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except AttributeError:  # pragma: no cover - knob renamed upstream
-            pass
-
-
-def _maybe_enable_cache_from_env() -> None:
-    global _cache_env_done
-    if _cache_env_done:
+    global _cache_enabled
+    if _cache_enabled:
         return
-    _cache_env_done = True
-    path = os.environ.get(CACHE_ENV)
-    if path:
-        enable_compilation_cache(path)
+    _cache_enabled = True
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    # the engine's executables are small and quick to build one by one
+    # but numerous (chunk shape x migration x precision), so cache
+    # everything regardless of size / compile time
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def _resolve_devices(devices, mesh) -> list:
@@ -1194,7 +1189,6 @@ class _ShardedRunner:
 
     def __call__(self, consts, state, *acc):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         struct = (tuple(sorted(consts)), tuple(sorted(state)))
@@ -1206,13 +1200,13 @@ class _ShardedRunner:
             if self._stats:
                 def body(c, s, a):
                     cs = step(c, s)["cell_sums"]
-                    return a + jax.lax.psum(cs, "lanes")
+                    return _fold(a, jax.lax.psum(cs, "lanes"))
 
                 fn = jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=self.mesh,
                         in_specs=(cspec, sspec, P()), out_specs=P(),
-                        check_rep=False,
+                        check_vma=False,
                     ),
                     donate_argnums=(1, 2),
                 )
@@ -1222,16 +1216,29 @@ class _ShardedRunner:
                     return {k: final[k] for k in _OUT_KEYS}
 
                 fn = jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=self.mesh,
                         in_specs=(cspec, sspec),
                         out_specs={k: P("lanes") for k in _OUT_KEYS},
-                        check_rep=False,
+                        check_vma=False,
                     ),
                     donate_argnums=(1,),
                 )
             self._jitted[struct] = fn
         return fn(consts, state, *acc)
+
+
+def _fold(acc, cs):
+    """Add one chunk's per-cell sums into the running accumulator.
+
+    The barrier keeps XLA from fusing the add into the segment
+    reduction (which would sum each lane into the accumulator in turn):
+    a chunk's sums are complete before they are folded in, so a call's
+    result equals the host-side sum of per-chunk results at the same
+    chunking bit for bit — the resumable campaign's contract."""
+    import jax
+
+    return acc + jax.lax.optimization_barrier(cs)
 
 
 def _get_runner(
@@ -1262,7 +1269,7 @@ def _get_runner(
         # fold this chunk's per-cell sums into the donated on-device
         # accumulator: the O(lanes) state never crosses the host boundary
         def run_stats(consts, state, acc):
-            return acc + step(consts, state)["cell_sums"]
+            return _fold(acc, step(consts, state)["cell_sums"])
 
         runner = jax.jit(run_stats, donate_argnums=(1, 2))
     else:
@@ -1618,8 +1625,12 @@ def _fetch(final, n_real: int):
     for k in _OUT_KEYS:
         final[k].copy_to_host_async()  # repro-lint: disable=host-sync
     out = {k: np.asarray(final[k])[:n_real] for k in _OUT_KEYS}
-    if not (out.pop("phase") == B._PH_DONE).all():  # pragma: no cover
-        raise RuntimeError("jax batch simulator did not converge")
+    n_open = int((out.pop("phase") != B._PH_DONE).sum())
+    if n_open:
+        raise RuntimeError(
+            f"jax batch simulator did not converge: {n_open} lane(s) "
+            "unfinished at max_iters"
+        )
     return out
 
 
@@ -1730,7 +1741,6 @@ def simulate_batch_jax(
     chunk: Union[int, str, None] = "auto",
     precision: str = "auto",
     use_pallas: bool = True,
-    interpret: Optional[bool] = None,
     devices=None,
     mesh=None,
     cell_index=None,
@@ -1778,12 +1788,11 @@ def simulate_batch_jax(
                 async pipeline), and results are fetched one chunk
                 behind the dispatch front.
     precision   "x64" (default off-TPU; float-rounding agreement with the
-                NumPy engine), "x32" (TPU default — no f64 on TPU), or
-                "auto".
+                NumPy engine), "x32" (the TPU default: f32 lanes,
+                Threefry-x32 draws), or "auto".
     use_pallas  run the hot primitive-update step as the Pallas kernel
-                (interpret-mode off-TPU); False uses the identical
-                pure-jnp body.
-    interpret   force/forbid Pallas interpret mode (default: off-TPU).
+                (compiled on a TPU, interpret-mode elsewhere); False uses
+                the identical pure-jnp body.
     devices     shard every chunk's lanes across these devices (None: the
                 default device; "all": every local device; an int n: the
                 first n local devices; or an explicit device sequence).
@@ -1807,7 +1816,7 @@ def simulate_batch_jax(
 
     import jax
 
-    _maybe_enable_cache_from_env()
+    enable_compilation_cache()
     is_spec = isinstance(traces, TraceSpec)
     spec_celled = is_spec and traces.cell_index is not None
     L = traces.n_lanes
@@ -1952,8 +1961,8 @@ def simulate_batch_jax(
     backend = devs[0].platform
     if precision == "auto":
         precision = "x32" if backend == "tpu" else "x64"
-    if interpret is None:
-        interpret = backend != "tpu"
+    # Mosaic compiles the kernels on a TPU; every other backend interprets
+    interpret = backend != "tpu"
     x64 = precision == "x64"
 
     if chunk == "auto":
@@ -1975,12 +1984,6 @@ def simulate_batch_jax(
     shard = -(-per_dev_lanes // quant) * quant
     n_pad = shard * n_dev
 
-    if x64 and not jax.config.jax_enable_x64:
-        from jax.experimental import enable_x64
-
-        ctx = enable_x64()
-    else:
-        ctx = contextlib.nullcontext()
     # fused sweeps: pad the cell table with benign rows to a power of two
     # (row n_cells is the sacrificial row padding lanes point at), so
     # similarly-sized grids share compiled executables
@@ -1995,7 +1998,7 @@ def simulate_batch_jax(
         n_tab = 0
         gathered, n_seg = (), 0
 
-    with ctx:
+    with jax.enable_x64(x64):
         fdt = np.float64 if x64 else np.float32
         idt = np.int64 if x64 else np.int32
         tables = None
@@ -2082,11 +2085,16 @@ def simulate_batch_jax(
     LAST_TIMINGS.update(
         trace_mode="device" if is_spec else "host",
         pack_s=t_pack, dispatch_s=t_dispatch, fetch_s=t_fetch,
-        n_chunks=n_chunks,
+        n_chunks=n_chunks, precision=precision,
+        pallas=("interpret" if interpret else "compiled") if use_pallas else "off",
     )
     if not want_lanes:
-        if cs[:n_cells, _CS_NOTDONE].sum() != 0.0:  # pragma: no cover
-            raise RuntimeError("jax batch simulator did not converge")
+        n_open = int(cs[:n_cells, _CS_NOTDONE].sum())
+        if n_open:
+            raise RuntimeError(
+                f"jax batch simulator did not converge: {n_open} lane(s) "
+                f"unfinished after max_iters={max_iters} iterations"
+            )
         return CellSums.from_matrix(cs[:n_cells])
     cat = {k: np.concatenate([o[k] for o in outs]) for k in outs[0]}
     return BatchResult(
@@ -2117,13 +2125,7 @@ def device_interarrival_samples(
     from ..kernels.sim_step import gap_transform, splitmix64
 
     E.require_inverse_cdf(dist)
-    if not jax.config.jax_enable_x64:
-        from jax.experimental import enable_x64
-
-        ctx = enable_x64()
-    else:
-        ctx = contextlib.nullcontext()
-    with ctx:
+    with jax.enable_x64(True):
         key = E.stream_key64_np(
             seed, np.asarray([stream], np.int64), E.STREAM_FAULT_GAP
         )

@@ -35,6 +35,12 @@ from . import periods as P
 #: far below any modelled quantity yet far above float64 residuals.
 _EPS = 1e-6
 
+#: ulps of the clock's own float type that the batched engines' period-,
+#: job- and window-end checks allow on top of ``_EPS`` (``clock_tol`` in
+#: ``batch_sim`` and its jnp twin in ``kernels.sim_step``); in float64
+#: ``_EPS`` always decides at simulated clocks below ~10^9 s
+_CLOCK_ULPS = 4
+
 __all__ = [
     "Strategy",
     "young",

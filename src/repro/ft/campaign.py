@@ -32,7 +32,10 @@ the campaign:
 * **persistent engine failure** — once the retry budget is exhausted,
   degrade ``engine="jax"`` to the NumPy ``"batch"`` engine for the rest
   of the campaign (same streams, host replay) and record the
-  degradation in the result metadata;
+  degradation in the result metadata.  Deterministic failures — a
+  kernel that does not lower or compile, a lane machine that does not
+  converge — classify as FATAL and raise: carrying them onto the host
+  engine would hide a broken device path behind a finished campaign;
 * **process kill** — nothing to do: the next incarnation resumes from
   the newest valid snapshot (:meth:`CheckpointStore.restore_latest`
   skips torn/corrupt ones).

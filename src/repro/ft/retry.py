@@ -36,7 +36,9 @@ class FailureKind(Enum):
     DEVICE_LOSS = "device_loss"
     #: unknown runtime error: retry as-is under the backoff budget
     TRANSIENT = "transient"
-    #: programming/config error: never retried, propagate immediately
+    #: programming/config error or a deterministic engine failure (the
+    #: kernel does not lower, the lane machine does not converge): never
+    #: retried and never degraded to another engine, propagate immediately
     FATAL = "fatal"
 
 
@@ -60,8 +62,21 @@ _DEVICE_LOSS_PATTERNS = (
     "NCCL",
 )
 
-#: exception types that signal a bug or bad configuration, not a fault
-_FATAL_TYPES = (TypeError, ValueError, KeyError, AttributeError, IndexError)
+#: message fragments of failures that recur on every attempt: Mosaic
+#: kernel lowering/compilation, unimplemented XLA operations, and the
+#: engine's own non-convergence error
+_FATAL_PATTERNS = (
+    "Mosaic",
+    "UNIMPLEMENTED",
+    "did not converge",
+)
+
+#: exception types that signal a bug, bad configuration or an operation
+#: the backend cannot lower — not a fault
+_FATAL_TYPES = (
+    TypeError, ValueError, KeyError, AttributeError, IndexError,
+    NotImplementedError,
+)
 
 
 def classify_failure(exc: BaseException) -> FailureKind:
@@ -74,6 +89,8 @@ def classify_failure(exc: BaseException) -> FailureKind:
     if isinstance(kind, FailureKind):
         return kind
     msg = f"{type(exc).__name__}: {exc}"
+    if any(p in msg for p in _FATAL_PATTERNS):
+        return FailureKind.FATAL
     if any(p in msg for p in _DEVICE_LOSS_PATTERNS):
         return FailureKind.DEVICE_LOSS
     if any(p in msg for p in _OOM_PATTERNS):

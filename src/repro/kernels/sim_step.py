@@ -43,12 +43,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-import numpy as np
-
 from ..core.events import (
     _SM_GAMMA, _SM_MIX1, _SM_MIX2, _TF_PARITY, _TF_ROTATIONS, THREEFRY_ROUNDS,
     LAW_EXPONENTIAL, LAW_LOGNORMAL, LAW_UNIFORM, LAW_WEIBULL,
 )
+from ..core.simulator import _CLOCK_ULPS
 
 __all__ = [
     "PRIM_NOOP",
@@ -61,6 +60,7 @@ __all__ = [
     "FLAG_FIN",
     "FLAG_CKPT_OK",
     "FLAG_REG",
+    "clock_tol",
     "primitive_update",
     "masked_primitive_update",
     "threefry2x32",
@@ -88,6 +88,23 @@ FLAG_OK = 2  # primitive completed without fault
 FLAG_FIN = 4  # the work segment finished the job
 FLAG_CKPT_OK = 8  # a checkpoint committed (saved <- saved + unsaved)
 FLAG_REG = 16  # ... and it was a *regular* (period-resetting) checkpoint
+
+
+# repro-twin: repro.core.batch_sim.clock_tol
+# repro-lint: jit-root
+def clock_tol(t, eps: float):
+    """Tolerance of an end-of-span check made at clock ``t``: the
+    absolute ``eps`` or ``_CLOCK_ULPS`` ulps of ``t``'s float type,
+    whichever is larger.
+
+    In float64 ``eps`` decides (4 ulps of a clock stay below 1e-6 s up to
+    ~10^9 s), so x64 runs compare against ``eps`` alone.  In float32 — the
+    TPU's arithmetic — a clock near 10^6 s moves in 0.0625 s steps: a
+    period or job end nearer than that to the clock cannot be reached by
+    any step, and a lane waiting for it would spin forever.  A few ulps
+    of slack end such a span at most ``_CLOCK_ULPS`` ulps early (under a
+    second at paper makespans), far below Monte-Carlo resolution."""
+    return jnp.maximum(eps, t * (_CLOCK_ULPS * jnp.finfo(t.dtype).eps))
 
 
 # repro-lint: jit-root
@@ -130,7 +147,7 @@ def primitive_update(
     unsaved2 = jnp.where(wok, unsaved1 + dt, unsaved1)
     pw2 = jnp.where(wok & creditb, pw1 + dt, pw1)
     t2 = jnp.where(wok, target, t1)
-    fin = wok & (saved + unsaved2 >= W - eps)
+    fin = wok & (saved + unsaved2 >= W - clock_tol(target, eps))
 
     iok = idlem & ok
     t3 = jnp.where(iok, target, t2)
@@ -196,9 +213,11 @@ def threefry2x32(k0, k1, c0, c1, rounds: int = THREEFRY_ROUNDS):
 # repro-lint: jit-root
 def uniform24(bits, dtype):
     """uint32 words -> uniforms in the open interval (0, 1) (top 24 bits,
-    half-ulp centered); see the NumPy twin in ``core.events``."""
+    half-ulp centered); see the NumPy twin in ``core.events``.  The
+    shifted word is below 2^24, so it goes through int32 on its way to
+    float: exact, and the only integer-to-float cast Mosaic lowers."""
     return (
-        (bits >> 8).astype(dtype) + jnp.asarray(0.5, dtype)
+        (bits >> 8).astype(jnp.int32).astype(dtype) + jnp.asarray(0.5, dtype)
     ) * jnp.asarray(2.0**-24, dtype)
 
 
@@ -219,13 +238,15 @@ def splitmix64(key64, ctr):
 
 
 # repro-lint: jit-root
-def stream_key(k0, k1):
-    """Pack a Threefry subkey pair into the per-draw key representation:
-    a single uint64 (SplitMix64 draws) when 64-bit integers are enabled
-    — the CPU/GPU x64 path, matching :meth:`TraceSpec.materialize` — or
-    the uint32 pair itself (Threefry draws) on x32/TPU, where uint64 is
-    unavailable."""
-    if jnp.zeros((), jnp.uint64).dtype == np.dtype("uint64"):
+def stream_key(k0, k1, *, x64: bool):
+    """Pack a Threefry subkey pair into the per-draw key representation
+    of an engine running at precision ``x64``: a single uint64
+    (SplitMix64 draws) in float64 — matching
+    :meth:`TraceSpec.materialize` — or the uint32 pair itself (Threefry
+    draws) in float32, the TPU's arithmetic, whose kernels have no
+    uint64.  Keyed on the engine's precision, never on the process-wide
+    x64 flag."""
+    if x64:
         return ((k0.astype(jnp.uint64) << 32) | k1.astype(jnp.uint64),)
     return (k0, k1)
 

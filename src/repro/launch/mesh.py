@@ -8,28 +8,19 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh_compat", "make_production_mesh"]
+__all__ = ["make_auto_mesh", "make_production_mesh"]
 
 
-def make_mesh_compat(shape, names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types across JAX versions.
-
-    ``jax.sharding.AxisType`` and the ``axis_types=`` keyword only exist in
-    newer JAX; older releases default to Auto semantics anyway.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, names, axis_types=(axis_type.Auto,) * len(names)
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, names)
+def make_auto_mesh(shape, names) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis of ``Auto`` type (sharding
+    propagated by the compiler, as the GSPMD rules expect)."""
+    return jax.make_mesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(names)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 (single v5e-class pod, 256 chips) or 2x16x16 (2 pods, 512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)

@@ -92,9 +92,7 @@ def dp_allreduce_int8(x: jax.Array, mesh, axis: str = "data") -> jax.Array:
         total = deq.sum(axis=0).reshape(-1)[: xs.size].reshape(xs.shape)
         return total
 
-    from jax.experimental.shard_map import shard_map
-
     spec = P(axis)
-    return shard_map(
-        kern, mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False
+    return jax.shard_map(
+        kern, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
     )(x)

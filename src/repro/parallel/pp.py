@@ -100,12 +100,10 @@ def pipeline_apply(
         owned = jnp.where(sid == n_stages - 1, outputs, jnp.zeros_like(outputs))
         return jax.lax.psum(owned, axis)
 
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params_stacked, x)

@@ -26,11 +26,11 @@ from repro.models.layers import RuntimeFlags
 from repro.models.transformer import LanguageModel
 from repro.optim.adamw import adamw_init
 
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 
 assert len(jax.devices()) == 8, jax.devices()
 
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_auto_mesh((2, 4), ("data", "model"))
 # chunked attention exercised via tiny dense_attn_max; capacity factor is
 # raised so no MoE tokens drop — capacity dropping is legitimately
 # locality-dependent (per-DP-group vs global), which would differ between

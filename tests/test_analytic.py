@@ -18,7 +18,6 @@ Four contracts pinned here:
   keep the old ad-hoc engine keywords behaviour-identical.
 """
 
-import contextlib
 import warnings
 from dataclasses import replace
 
@@ -54,12 +53,8 @@ except ImportError:  # pragma: no cover - optional dependency
 
 
 def _x64():
-    """x64 tracing context (no-op when the session already enables it)."""
-    if jax.config.jax_enable_x64:
-        return contextlib.nullcontext()
-    from jax.experimental import enable_x64
-
-    return enable_x64()
+    """x64 tracing context."""
+    return jax.enable_x64(True)
 
 
 def _scalar_waste(cell: ExperimentCell) -> float:

@@ -396,6 +396,37 @@ class TestChaosRecovery:
             key_vec(ref_device)[0], key_vec(res)[0], rtol=0.35
         )
 
+    @pytest.mark.parametrize("failure", ["lowering", "nonconvergence"])
+    def test_deterministic_failure_raises_not_degrades(
+        self, tmp_path, grid, monkeypatch, failure
+    ):
+        """A kernel the backend cannot lower, or a lane machine that does
+        not converge, fails every attempt alike: the campaign raises it
+        instead of finishing the sweep on the host's batch engine."""
+        import functools
+
+        from repro.core import jax_sim
+
+        if failure == "lowering":
+            def engine(*a, **kw):
+                raise NotImplementedError("Unsupported cast: uint32 -> float32")
+
+            expect = NotImplementedError
+        else:
+            engine = functools.partial(jax_sim.simulate_batch_jax, max_iters=1)
+            expect = RuntimeError
+        monkeypatch.setattr(jax_sim, "simulate_batch_jax", engine)
+        runner = CampaignRunner(
+            grid,
+            CampaignConfig(ckpt_dir=str(tmp_path), ckpt_period=0.0,
+                           retry=nosleep()),
+            cfg("device"),
+        )
+        with pytest.raises(expect):
+            runner.run()
+        assert not runner._degraded
+        assert not runner._events
+
     def test_degraded_state_survives_kill(self, tmp_path, grid):
         """Degradation is durable: a campaign killed *after* degrading
         resumes on the batch engine, bit-identical to an uninterrupted
@@ -466,6 +497,14 @@ class TestRetryPrimitives:
         ) is FailureKind.OOM
         assert classify_failure(ValueError("bad arg")) is FailureKind.FATAL
         assert classify_failure(RuntimeError("???")) is FailureKind.TRANSIENT
+        # deterministic engine failures: lowering, compiling, converging
+        for exc in (
+            NotImplementedError("Unsupported cast: uint32 -> float32"),
+            RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel"),
+            RuntimeError("UNIMPLEMENTED: no lowering for this op"),
+            RuntimeError("jax batch simulator did not converge: 3 lane(s)"),
+        ):
+            assert classify_failure(exc) is FailureKind.FATAL
 
     def test_backoff_deterministic_and_bounded(self):
         pol = RetryPolicy(base=0.1, factor=2.0, jitter=0.5, seed=4)

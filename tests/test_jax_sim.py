@@ -262,8 +262,11 @@ def test_sharded_invariance_subprocess():
 
 
 @pytest.mark.slow
-def test_persistent_compilation_cache_env(tmp_path):
-    """REPRO_JAX_CACHE_DIR populates a persistent compilation cache.
+@pytest.mark.parametrize("from_env", [True, False])
+def test_persistent_compilation_cache_env(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, where set, receives the persistent
+    compilation cache and the engine sets no directory of its own;
+    unset, the cache lives at the one fixed path in the checkout.
 
     Subprocess: the cache directory must be configured before the jax
     backend initializes, which has long happened in the test process."""
@@ -271,14 +274,19 @@ def test_persistent_compilation_cache_env(tmp_path):
     import subprocess
     import sys
 
+    from repro.core.jax_sim import DEFAULT_CACHE_DIR
+
     cache = tmp_path / "jax-cache"
     env = dict(os.environ)
-    env["REPRO_JAX_CACHE_DIR"] = str(cache)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
     env["PYTHONPATH"] = (
         os.path.join(os.path.dirname(__file__), "..", "src")
         + os.pathsep + env.get("PYTHONPATH", "")
     )
     body = (
+        "import jax\n"
         "import numpy as np\n"
         "from repro.core import Platform, PredictorModel, "
         "make_event_traces_batch\n"
@@ -289,15 +297,22 @@ def test_persistent_compilation_cache_env(tmp_path):
         "tr = make_event_traces_batch(np.random.default_rng(0), 2, "
         "horizon=1e6, mtbf=plat.mu, recall=0.0, precision=1.0, window=0.0)\n"
         "simulate_batch_jax(86400.0, plat, S.young(plat), tr)\n"
+        "print('CACHE_DIR=' + jax.config.jax_compilation_cache_dir)\n"
     )
     proc = subprocess.run(
         [sys.executable, "-c", body], capture_output=True, text=True,
         env=env, timeout=600,
     )
     assert proc.returncode == 0, proc.stderr
-    assert cache.is_dir() and any(cache.iterdir()), (
+    used = proc.stdout.split("CACHE_DIR=")[1].strip()
+    want = str(cache) if from_env else DEFAULT_CACHE_DIR
+    assert used == want
+    assert os.path.isdir(want) and os.listdir(want), (
         "persistent compilation cache is empty"
     )
+    if not from_env:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert want == os.path.join(repo, ".jax_cache")
 
 
 def test_simulate_many_jax_engine():

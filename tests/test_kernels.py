@@ -187,8 +187,6 @@ class TestCounterRNG:
             assert (int(x0) << 32) | int(x1) == want
 
     def test_numpy_vs_jnp_bit_equality(self):
-        from jax.experimental import enable_x64
-
         from repro.core import events as E
         from repro.kernels import sim_step as K
 
@@ -201,7 +199,7 @@ class TestCounterRNG:
             b = K.threefry2x32(k0, k1, c0, c1, rounds=rounds)
             np.testing.assert_array_equal(a[0], np.asarray(b[0]))
             np.testing.assert_array_equal(a[1], np.asarray(b[1]))
-        with enable_x64():
+        with jax.enable_x64(True):
             key = rng.integers(0, 2**64, size=129, dtype=np.uint64)
             ctr = rng.integers(0, 2**20, size=129).astype(np.int64)
             a = E.splitmix64(key, ctr)
@@ -212,16 +210,14 @@ class TestCounterRNG:
     def test_pallas_stream_advance_matches_jnp(self):
         """The Pallas sampling kernel entry and the shared jnp body are
         bit-identical (interpret mode on CPU)."""
-        from jax.experimental import enable_x64
-
         from repro.core import events as E
         from repro.kernels import sim_step as K
 
-        with enable_x64():
+        with jax.enable_x64(True):
             L = 256
             rng = np.random.default_rng(11)
             k0, k1 = E.stream_subkey_np(7, np.arange(L), E.STREAM_FAULT_GAP)
-            key = K.stream_key(jnp.asarray(k0), jnp.asarray(k1))
+            key = K.stream_key(jnp.asarray(k0), jnp.asarray(k1), x64=True)
             mask = jnp.asarray(rng.random(L) < 0.7)
             ctr = jnp.asarray(rng.integers(0, 50, L), jnp.int32)
             tm = jnp.asarray(rng.random(L) * 1e5, jnp.float64)

@@ -26,9 +26,9 @@ SCRIPT = textwrap.dedent(
     import numpy as np
     from repro.parallel.pp import pipeline_apply
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
 
-    mesh = make_mesh_compat((4,), ("stage",))
+    mesh = make_auto_mesh((4,), ("stage",))
     S, D, MB, NM = 4, 16, 8, 6
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)

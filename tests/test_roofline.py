@@ -19,9 +19,9 @@ SCRIPT = textwrap.dedent(
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.launch.hlo_analysis import analyze_hlo
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
 
     L, D, F, B = 3, 64, 128, 16
     def step(w1, w2, x):

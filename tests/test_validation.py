@@ -377,3 +377,35 @@ def test_cell_z_rows_margin_sides(paper_sweep):
         else:
             assert r.margin >= 0.10 * abs(r.analytic) + 0.004 - 1e-12
             assert r.margin <= 0.55 * abs(r.analytic) + 0.004 + 1e-12
+
+
+def test_x32_paper_length_cell_matches_theory():
+    """The TPU's float32 clock finishes a paper-length job and keeps the
+    contract: p82r85/N16384/Young (8-day job, makespans near 7e5 s, where
+    a float32 clock steps 0.0625 s) converges at ``precision="x32"`` with
+    no exhausted lane, and its mean waste sits inside the cell's
+    validation margin.  With an absolute 1e-6 s end-of-period tolerance
+    the lanes stop advancing and never finish."""
+    from repro.core.jax_sim import LAST_TIMINGS, simulate_batch_jax
+    from repro.experiments.runner import _stats_cell_result, build_fused_layout
+
+    cell = next(
+        c for c in paper_grid_cells("validation")
+        if c.label == "p82r85/N16384/Young"
+    )
+    assert cell.work == 8 * 86400.0
+    grid = GridSpec((cell,), n_runs=64, seed=SEED)
+    lay = build_fused_layout(grid, "device")
+    sums = simulate_batch_jax(
+        lay.work_c, lay.plats_c, lay.strats_c, lay.concat_spec(),
+        precision="x32", collect="stats", max_iters=100_000,
+    )
+    assert LAST_TIMINGS["precision"] == "x32"
+    assert sums.n[0] == 64 and sums.n_exhausted[0] == 0
+    assert np.asarray(sums.mean_makespan)[0] > 6e5
+    sweep = SweepResult(
+        grid=grid, cells=[_stats_cell_result(cell, sums, 0)], engine="jax",
+        wall_time_s=0.0, dispatch="fused", collect="stats",
+    )
+    rows, fails = validate_sweep(sweep, alpha=ALPHA)
+    assert not fails and rows[0].se_sim > 0
