@@ -1,0 +1,8 @@
+"""Host packing per query: ``jax_sim.LAST_TIMINGS["pack_s"]`` of each
+query's ``run_grid`` call in the window, in milliseconds (the chunk
+pipeline's host share)."""
+
+
+def read(ctx):
+    v = ctx["layer"].get("pack_s") or []
+    return 1e3 * sum(v) / len(v) if v else None
