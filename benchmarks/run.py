@@ -41,13 +41,6 @@ def main() -> None:
         "BENCH_sim.json, or BENCH_sim.<module>.json under --only so "
         "partial runs never clobber the full tracking file)",
     )
-    ap.add_argument(
-        "--profile", nargs="?", const="bench-profile", default=None,
-        metavar="DIR",
-        help="wrap each benchmark module in jax.profiler.trace(DIR) "
-        "(default DIR: bench-profile) and record the trace directory in "
-        "the bench JSON; skipped with a warning if jax is unavailable",
-    )
     args = ap.parse_args()
     if args.only and args.only not in MODULE_NAMES:
         ap.exit(
@@ -67,26 +60,13 @@ def main() -> None:
         for name in MODULE_NAMES
         if not args.only or name == args.only
     }
-    profile_ctx = None
-    if args.profile is not None:
-        try:
-            import jax.profiler as _jp
-
-            profile_ctx = lambda: _jp.trace(args.profile)
-        except Exception as exc:  # pragma: no cover - env-dependent
-            print(f"# --profile unavailable ({exc}); running unprofiled",
-                  file=sys.stderr)
-
-    import contextlib
-
     common.reset_records()
     print("name,us_per_call,derived")
     t0 = time.monotonic()
     ran = []
     for name, mod in modules.items():
         print(f"# == {name} ==", file=sys.stderr, flush=True)
-        with profile_ctx() if profile_ctx else contextlib.nullcontext():
-            mod.run(quick=not args.full)
+        mod.run(quick=not args.full)
         ran.append(name)
     total = time.monotonic() - t0
     print(f"# total {total:.1f}s", file=sys.stderr)
@@ -96,8 +76,6 @@ def main() -> None:
             "modules": ran,
             "total_s": round(total, 1),
         }
-        if args.profile is not None and profile_ctx is not None:
-            meta["profile_trace_dir"] = args.profile
         common.write_records_json(args.json, meta=meta)
         print(f"# wrote {args.json}", file=sys.stderr)
         if "jax_engine" in ran and not args.only:

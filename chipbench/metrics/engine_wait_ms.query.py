@@ -1,0 +1,12 @@
+"""The host's wait on the device per query, in milliseconds: the
+program's ``repro.engine.wait`` spans (``block_until_ready`` before the
+fetch) of one ``run_grid`` call, mean over the calls that opened and
+closed in the traced window."""
+
+from chipbench import program_trace as P
+
+
+def read(ctx):
+    return P.mean_ms(
+        P.recorded(), "repro.run_grid", lambda c: c["repro.engine.wait"]
+    )

@@ -281,9 +281,9 @@ def _spy_dispatch(captures: list, passthrough: bool):
 
     ``passthrough=False`` raises :class:`_AuditDone` after the first
     capture (lanes mode: nothing fabricates per-lane results);
-    ``passthrough=True`` returns the accumulator untouched so the chunk
-    loop — and a whole ``run_grid`` sweep — completes without ever
-    executing a compiled program."""
+    ``passthrough=True`` returns the accumulator untouched (and a zero
+    loop count) so the chunk loop — and a whole ``run_grid`` sweep —
+    completes without ever executing a compiled program."""
     from repro.core import jax_sim
 
     orig = jax_sim._dispatch
@@ -291,7 +291,9 @@ def _spy_dispatch(captures: list, passthrough: bool):
     def spy(runner, devs, consts, state, *acc):
         captures.append(_Capture(runner, devs, consts, state, acc))
         if passthrough and acc:
-            return acc[0]
+            import jax
+
+            return acc[0], jax.device_put(np.int32(0))
         raise _AuditDone
 
     jax_sim._dispatch = spy

@@ -46,6 +46,7 @@ from . import events as E
 from . import periods as P
 from . import waste as W
 from .periods import OptimalPolicy
+from .spans import span
 from .waste import Platform, PredictorModel, i_prime
 
 __all__ = [
@@ -424,10 +425,14 @@ def newton_optimize_tables(
     The table is padded to a pow2 row count with the engine's benign
     rows before dispatch so similarly-sized grids share one compiled
     executable; padding rows are dropped from the result."""
-    import jax
+    return _newton_solve(*_newton_inputs(tables, alpha, capped), iters, devices)
 
-    from ..kernels import analytic as K
 
+def _newton_inputs(
+    tables: Dict[str, np.ndarray], alpha: float, capped: bool
+) -> Tuple[list, int]:
+    """The host half of :func:`newton_optimize_tables`: the padded
+    argument columns of ``newton_policy`` and the count of real rows."""
     defaults = {"C2": 0.0, "DR2": 0.0, "V": 0.0, "fmem": 0.0,
                 "rho": 1.0, "kv": 1.0}
     if any(k not in tables for k in defaults):
@@ -451,25 +456,36 @@ def newton_optimize_tables(
     else:
         tables_p = tables
     lo, hi0, hi1 = _newton_bounds(tables_p, alpha, capped)
+    t = {
+        k: np.asarray(tables_p[k]).astype(
+            np.int32 if k == "mode" else np.float64
+        )
+        for k in TABLE_COLS + ("fp_mean",)
+    }
+    with np.errstate(invalid="ignore"):
+        p = precision_from_fp(t["mtbf"], t["fp_mean"], t["recall"])
+    args = [
+        t["mode"], t["q_eff"], t["C"], t["DR"], t["lead_act"],
+        t["mtbf"], t["recall"], p, t["window"], t["T_P"],
+        t["tp_eff_default"], t["C2"], t["DR2"], t["V"], t["fmem"],
+        t["rho"], t["kv"], lo, hi0, hi1,
+    ]
+    return args, n
+
+
+def _newton_solve(
+    args: list, n: int, iters: int = 60, devices=None
+) -> Dict[str, np.ndarray]:
+    """The device half of :func:`newton_optimize_tables`: place the
+    columns, solve in one dispatch, fetch the ``n`` real rows."""
+    import jax
+
+    from ..kernels import analytic as K
 
     with jax.enable_x64(True):
         dev = None
         if devices:
             dev = devices[0] if isinstance(devices, (list, tuple)) else devices
-        t = {
-            k: np.asarray(tables_p[k]).astype(
-                np.int32 if k == "mode" else np.float64
-            )
-            for k in TABLE_COLS + ("fp_mean",)
-        }
-        with np.errstate(invalid="ignore"):
-            p = precision_from_fp(t["mtbf"], t["fp_mean"], t["recall"])
-        args = [
-            t["mode"], t["q_eff"], t["C"], t["DR"], t["lead_act"],
-            t["mtbf"], t["recall"], p, t["window"], t["T_P"],
-            t["tp_eff_default"], t["C2"], t["DR2"], t["V"], t["fmem"],
-            t["rho"], t["kv"], lo, hi0, hi1,
-        ]
         if dev is not None:
             args = [jax.device_put(a, dev) for a in args]
         out = K.newton_policy(*args, iters=iters)
@@ -602,18 +618,21 @@ def _newton_policies(
         for f in fams:
             cand_names.append(f)
             cand_items.append(i)
-    strategies = [
-        _strategy_stub(f, platforms[i], preds[i])
-        for f, i in zip(cand_names, cand_items)
-    ]
-    tabs = cell_tables(
-        0.0,
-        [platforms[i] for i in cand_items],
-        [preds[i] for i in cand_items],
-        strategies,
-        0.0,
-    )
-    sol = newton_optimize_tables(tabs, alpha=alpha, capped=capped, devices=devices)
+    with span("repro.optimize.tables"):
+        strategies = [
+            _strategy_stub(f, platforms[i], preds[i])
+            for f, i in zip(cand_names, cand_items)
+        ]
+        tabs = cell_tables(
+            0.0,
+            [platforms[i] for i in cand_items],
+            [preds[i] for i in cand_items],
+            strategies,
+            0.0,
+        )
+        inputs = _newton_inputs(tabs, alpha, capped)
+    with span("repro.optimize.solve"):
+        sol = _newton_solve(*inputs, devices=devices)
     n = len(names)
     best = np.full(n, np.inf)
     idx = np.full(n, -1, np.int64)
@@ -683,6 +702,20 @@ def optimize(
     capped      restrict periods to the Section 3.2/4.3 validity domain
                 (the paper's own simulations use the uncapped default).
     """
+    n = len(strategy) if isinstance(strategy, (list, tuple)) else 1
+    with span("repro.optimize", method=method, cells=n):
+        return _optimize(
+            strategy, platform, pred, objective, method, alpha, capped,
+            engine, devices, mesh, config, work, n_runs, seed, fault_dist,
+            grid,
+        )
+
+
+def _optimize(
+    strategy, platform, pred, objective, method, alpha, capped, engine,
+    devices, mesh, config, work, n_runs, seed, fault_dist, grid,
+):
+    """:func:`optimize` inside its span."""
     if objective not in ("waste", "availability"):
         raise ValueError(
             f"unknown objective {objective!r} "

@@ -145,6 +145,7 @@ from . import events as E
 from .batch_sim import BatchResult, pad_lane_axis
 from .events import BatchTraces, TraceSpec, pad_sentinel
 from .simulator import Strategy, _EPS
+from .spans import span
 from .waste import Platform
 
 __all__ = [
@@ -158,14 +159,19 @@ __all__ = [
     "SHARD_TILE",
 ]
 
-#: host-side time split of the most recent :func:`simulate_batch_jax`
-#: call: {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks"},
-#: plus what the call resolved: ``precision`` ("x64"/"x32") and
-#: ``pallas`` ("compiled", "interpret" or "off").
-#: ``pack_s`` is host NumPy packing (events for the host trace mode,
-#: O(lanes) scalars for device mode), ``dispatch_s`` device_put + async
-#: launch, ``fetch_s`` the device wait + D2H copies.  Benchmarks read it
-#: to attribute end-to-end time.
+#: what the most recent :func:`simulate_batch_jax` call did:
+#: {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks",
+#: "loop_iters"}, plus what the call resolved: ``precision``
+#: ("x64"/"x32") and ``pallas`` ("compiled", "interpret" or "off").
+#: The seconds are the host durations of the call's spans
+#: (:mod:`repro.core.spans`): ``pack_s`` the ``repro.engine.pack`` spans,
+#: host NumPy packing (events for the host trace mode, O(lanes) scalars
+#: for device mode); ``dispatch_s`` the ``repro.engine.dispatch`` spans,
+#: device_put + async launch; ``fetch_s`` the ``repro.engine.wait`` and
+#: ``repro.engine.fetch`` spans, the device wait + D2H copies.
+#: ``loop_iters`` is an int64 ``(n_chunks, n_devices)`` array: the outer
+#: while loop's iterations for each chunk on each device, fetched with
+#: the results.  Benchmarks read it to attribute end-to-end time.
 LAST_TIMINGS: dict = {}
 
 #: lane-count granularity: 8 f32 sublanes x 128 lanes, the Pallas tile
@@ -750,20 +756,22 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             if f_kind == "indexed":
                 kw["stream"] += (f_law, f_lp[0], f_lp[1])
             kw["gap"] = (f_kind, f_param)
-            t, saved, unsaved, period_work, flags, sf_ctr, sf_time = upd(
-                prim, cont, target, ckend, nf,
-                t, saved, unsaved, period_work, W, DR,
-                eps=eps, reg_cont=int(B._C_CKPTREG), **kw,
-            )
+            with jax.named_scope("step_kernel"):
+                t, saved, unsaved, period_work, flags, sf_ctr, sf_time = upd(
+                    prim, cont, target, ckend, nf,
+                    t, saved, unsaved, period_work, W, DR,
+                    eps=eps, reg_cont=int(B._C_CKPTREG), **kw,
+                )
             if has_silent:
                 sf_ctr = jnp.where(sil_m, sil_ctr, sf_ctr)
                 sf_time = jnp.where(sil_m, sil_time, sf_time)
         else:
-            t, saved, unsaved, period_work, flags = upd(
-                prim, cont, target, ckend, nf,
-                t, saved, unsaved, period_work, W, DR,
-                eps=eps, reg_cont=int(B._C_CKPTREG), **kw,
-            )
+            with jax.named_scope("step_kernel"):
+                t, saved, unsaved, period_work, flags = upd(
+                    prim, cont, target, ckend, nf,
+                    t, saved, unsaved, period_work, W, DR,
+                    eps=eps, reg_cont=int(B._C_CKPTREG), **kw,
+                )
         faulted = (flags & FLAG_FAULTED) != 0
         ok = (flags & FLAG_OK) != 0
         fin = (flags & FLAG_FIN) != 0
@@ -1035,6 +1043,7 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
         state.setdefault("corrupt", jnp.full_like(state["t"], jnp.inf))
 
     n_it, final = lax.while_loop(cond, step, (jnp.int32(0), state))
+    # the outer loop's iteration count rides out beside the results
     final = dict(final); final["_iters"] = n_it
     if n_seg:
         # per-cell segment reduction on device: one (n_seg, 13) matrix of
@@ -1197,15 +1206,19 @@ class _ShardedRunner:
             cspec = {k: self._pspec(k) for k in consts}
             sspec = {k: self._pspec(k) for k in state}
             step = self._step
+            # each device's outer-loop count leaves as one row of
+            # ``_iters``: (n_dev,) after the gather
             if self._stats:
                 def body(c, s, a):
-                    cs = step(c, s)["cell_sums"]
-                    return _fold(a, jax.lax.psum(cs, "lanes"))
+                    final = step(c, s)
+                    cs = jax.lax.psum(final["cell_sums"], "lanes")
+                    return _fold(a, cs), final["_iters"].reshape(1)
 
                 fn = jax.jit(
                     jax.shard_map(
                         body, mesh=self.mesh,
-                        in_specs=(cspec, sspec, P()), out_specs=P(),
+                        in_specs=(cspec, sspec, P()),
+                        out_specs=(P(), P("lanes")),
                         check_vma=False,
                     ),
                     donate_argnums=(1, 2),
@@ -1213,13 +1226,15 @@ class _ShardedRunner:
             else:
                 def body(c, s):
                     final = step(c, s)
-                    return {k: final[k] for k in _OUT_KEYS}
+                    out = {k: final[k] for k in _OUT_KEYS}
+                    out["_iters"] = final["_iters"].reshape(1)
+                    return out
 
                 fn = jax.jit(
                     jax.shard_map(
                         body, mesh=self.mesh,
                         in_specs=(cspec, sspec),
-                        out_specs={k: P("lanes") for k in _OUT_KEYS},
+                        out_specs={k: P("lanes") for k in _OUT_KEYS + ("_iters",)},
                         check_vma=False,
                     ),
                     donate_argnums=(1,),
@@ -1269,7 +1284,8 @@ def _get_runner(
         # fold this chunk's per-cell sums into the donated on-device
         # accumulator: the O(lanes) state never crosses the host boundary
         def run_stats(consts, state, acc):
-            return _fold(acc, step(consts, state)["cell_sums"])
+            final = step(consts, state)
+            return _fold(acc, final["cell_sums"]), final["_iters"]
 
         runner = jax.jit(run_stats, donate_argnums=(1, 2))
     else:
@@ -1601,6 +1617,82 @@ def _dispatch(runner, devs, consts, state, *acc):
         return runner(consts, state, *acc)
 
 
+def _spec_draws(traces: TraceSpec, mode, q):
+    """What a device-trace call draws on the device: ``(gen, q_eff,
+    f_laws, fp_laws)``, the static draw specialization ``gen``, the
+    engine-side trust and the law tables of mixed-law specs."""
+
+    def _dist_static(d):
+        # mixed-law specs carry one Distribution per cell (or lane):
+        # the static (kind, param) specialization collapses to the
+        # "indexed" sentinel and the laws travel as data tables
+        if isinstance(d, tuple):
+            for x in d:
+                E.require_inverse_cdf(x)
+            return "indexed", 0.0
+        E.require_inverse_cdf(d)
+        return d.kind, float(d.param)
+
+    f_kind, f_param = _dist_static(traces.fault_dist)
+    fp_kind, fp_param = _dist_static(traces.false_pred_dist)
+    f_laws = (
+        E.law_table(traces.fault_dist) if f_kind == "indexed" else None
+    )
+    fp_laws = (
+        E.law_table(traces.false_pred_dist)
+        if fp_kind == "indexed" else None
+    )
+    # engine-side trust: mode "none" / q<=0 sees no predictions,
+    # fractional q thins both prediction streams via trust coins
+    # (per-cell arrays in the fused layout — the gathered per-lane
+    # values are identical, so is the compiled program); silent-error
+    # lanes never trust the fail-stop predictor
+    q_eff = np.where(
+        (mode == B._M_NONE) | (mode == B._M_SILENT),
+        0.0, np.clip(q, 0.0, 1.0),
+    )
+    frac_q = bool(((q_eff > 0.0) & (q_eff < 1.0)).any())
+    return (f_kind, f_param, fp_kind, fp_param, frac_q), q_eff, f_laws, fp_laws
+
+
+def _host_slabs(traces: BatchTraces, q, mode, fmem, any_tl: bool, rng):
+    """A host-trace call's padded event slabs ``(F, P0, Pft, Ftier)``:
+    fault times, the trusted predictions' dates and fault times, and
+    (two-level lanes only, else None) each fault's tier."""
+    p_t0, p_ft, _ = B._filter_trusted(traces, q, mode, rng)
+    # pow2-rounded sentinel widths: chunks (and similarly-sized
+    # batches) hit the same compiled executable
+    F = pad_sentinel(traces.fault_times, traces.n_faults, np.inf,
+                     round_pow2=True, min_width=8)
+    P0 = pad_sentinel(p_t0, traces.n_preds, np.inf,
+                      round_pow2=True, min_width=8)
+    Pft = pad_sentinel(p_ft, traces.n_preds, np.nan,
+                       round_pow2=True, min_width=8)
+    if not any_tl:
+        return F, P0, Pft, None
+    FT = getattr(traces, "fault_tier", None)
+    if FT is None:
+        tl_lanes = mode == B._M_TWO_LEVEL
+        if float(fmem[tl_lanes].max(initial=0.0)) > 0.0:
+            raise ValueError(
+                "two-level lanes with f > 0 need per-fault tier "
+                "draws: generate traces with "
+                "make_event_traces_batch(..., tier=True)"
+            )
+        FT = np.ones_like(traces.fault_times)
+    elif FT.shape[1] < traces.fault_times.shape[1]:
+        FT = np.concatenate(
+            [FT, np.ones(
+                (FT.shape[0],
+                 traces.fault_times.shape[1] - FT.shape[1])
+            )],
+            axis=1,
+        )
+    Ftier = pad_sentinel(FT, traces.n_faults, 1.0,
+                         round_pow2=True, min_width=8)
+    return F, P0, Pft, Ftier
+
+
 def _acc_init(n_seg: int, fdt, devs):
     """Zeroed on-device ``(n_seg, 13)`` CellSums accumulator.
 
@@ -1620,11 +1712,10 @@ def _acc_init(n_seg: int, fdt, devs):
 
 
 def _fetch(final, n_real: int):
-    """Pull one dispatched chunk's per-lane results back to the host."""
-    # the engine's one designed D2H point for per-lane results
-    for k in _OUT_KEYS:
-        final[k].copy_to_host_async()  # repro-lint: disable=host-sync
+    """Pull one dispatched chunk's per-lane results back to the host,
+    with its outer-loop count per device under ``"_iters"``."""
     out = {k: np.asarray(final[k])[:n_real] for k in _OUT_KEYS}
+    out["_iters"] = np.asarray(final["_iters"]).reshape(-1)
     n_open = int((out.pop("phase") != B._PH_DONE).sum())
     if n_open:
         raise RuntimeError(
@@ -1632,6 +1723,19 @@ def _fetch(final, n_real: int):
             "unfinished at max_iters"
         )
     return out
+
+
+def _collect(timings: dict, final, n_real: int):
+    """Wait for one dispatched chunk, then :func:`_fetch` it; both add
+    to ``timings["fetch_s"]``.  The copies are queued before the wait,
+    so they start as the chunk ends: the wait adds no sync."""
+    # the engine's one designed D2H point for per-lane results
+    for k in _OUT_KEYS + ("_iters",):
+        final[k].copy_to_host_async()  # repro-lint: disable=host-sync
+    with span("repro.engine.wait", timings, "fetch_s"):
+        final["t"].block_until_ready()  # repro-lint: disable=host-sync
+    with span("repro.engine.fetch", timings, "fetch_s"):
+        return _fetch(final, n_real)
 
 
 #: column order of the device-side per-cell segment reduction
@@ -1812,8 +1916,6 @@ def simulate_batch_jax(
                 "stats" (requires ``cell_index``): device-reduced
                 per-cell :class:`CellSums`.
     """
-    import time as _time
-
     import jax
 
     enable_compilation_cache()
@@ -1860,18 +1962,20 @@ def simulate_batch_jax(
             raise ValueError(
                 f"cell_index entries must be in [0, {n_cells})"
             )
-    W, C, D, R, M, T_R, T_P, mode, q, C2, R2, V, fmem, rho, kv = (
-        B._lane_params(work, platform, strategy, n_cells if celled else L)
-    )
-    if celled and not is_spec:
-        # host event arrays are inherently per-lane: broadcast the cell
-        # table host-side (cheap NumPy gathers) and keep only the
-        # lane -> cell index for the device-side per-cell reduction
+    timings = {"pack_s": 0.0, "dispatch_s": 0.0, "fetch_s": 0.0}
+    with span("repro.engine.prepare", lanes=L, cells=n_cells):
         W, C, D, R, M, T_R, T_P, mode, q, C2, R2, V, fmem, rho, kv = (
-            a[cidx_g] for a in (
-                W, C, D, R, M, T_R, T_P, mode, q, C2, R2, V, fmem, rho, kv
-            )
+            B._lane_params(work, platform, strategy, n_cells if celled else L)
         )
+        if celled and not is_spec:
+            # host event arrays are inherently per-lane: broadcast the cell
+            # table host-side (cheap NumPy gathers) and keep only the
+            # lane -> cell index for the device-side per-cell reduction
+            W, C, D, R, M, T_R, T_P, mode, q, C2, R2, V, fmem, rho, kv = (
+                a[cidx_g] for a in (
+                    W, C, D, R, M, T_R, T_P, mode, q, C2, R2, V, fmem, rho, kv
+                )
+            )
     # two-level / silent phase families are specialized out of every
     # other sweep's compiled step (and its packed payload), like migration
     any_tl = bool((mode == B._M_TWO_LEVEL).any())
@@ -1884,77 +1988,14 @@ def simulate_batch_jax(
         z = np.zeros(0)
         zi = np.zeros(0, np.int64)
         return BatchResult(z, z, zi, zi, zi, zi, np.zeros(0, bool))
-    t_pack = t_dispatch = t_fetch = 0.0
-    t0 = _time.monotonic()
-    if is_spec:
-        def _dist_static(d):
-            # mixed-law specs carry one Distribution per cell (or lane):
-            # the static (kind, param) specialization collapses to the
-            # "indexed" sentinel and the laws travel as data tables
-            if isinstance(d, tuple):
-                for x in d:
-                    E.require_inverse_cdf(x)
-                return "indexed", 0.0
-            E.require_inverse_cdf(d)
-            return d.kind, float(d.param)
-
-        f_kind, f_param = _dist_static(traces.fault_dist)
-        fp_kind, fp_param = _dist_static(traces.false_pred_dist)
-        f_laws = (
-            E.law_table(traces.fault_dist) if f_kind == "indexed" else None
-        )
-        fp_laws = (
-            E.law_table(traces.false_pred_dist)
-            if fp_kind == "indexed" else None
-        )
-        # engine-side trust: mode "none" / q<=0 sees no predictions,
-        # fractional q thins both prediction streams via trust coins
-        # (per-cell arrays in the fused layout — the gathered per-lane
-        # values are identical, so is the compiled program); silent-error
-        # lanes never trust the fail-stop predictor
-        q_eff = np.where(
-            (mode == B._M_NONE) | (mode == B._M_SILENT),
-            0.0, np.clip(q, 0.0, 1.0),
-        )
-        frac_q = bool(((q_eff > 0.0) & (q_eff < 1.0)).any())
-        gen = (f_kind, f_param, fp_kind, fp_param, frac_q)
-        fp_mean = traces.fp_mean
-        F = P0 = Pft = None
-    else:
-        gen = None
-        p_t0, p_ft, _ = B._filter_trusted(traces, q, mode, rng)
-        # pow2-rounded sentinel widths: chunks (and similarly-sized
-        # batches) hit the same compiled executable
-        F = pad_sentinel(traces.fault_times, traces.n_faults, np.inf,
-                         round_pow2=True, min_width=8)
-        P0 = pad_sentinel(p_t0, traces.n_preds, np.inf,
-                          round_pow2=True, min_width=8)
-        Pft = pad_sentinel(p_ft, traces.n_preds, np.nan,
-                           round_pow2=True, min_width=8)
-        if any_tl:
-            FT = getattr(traces, "fault_tier", None)
-            if FT is None:
-                tl_lanes = mode == B._M_TWO_LEVEL
-                if float(fmem[tl_lanes].max(initial=0.0)) > 0.0:
-                    raise ValueError(
-                        "two-level lanes with f > 0 need per-fault tier "
-                        "draws: generate traces with "
-                        "make_event_traces_batch(..., tier=True)"
-                    )
-                FT = np.ones_like(traces.fault_times)
-            elif FT.shape[1] < traces.fault_times.shape[1]:
-                FT = np.concatenate(
-                    [FT, np.ones(
-                        (FT.shape[0],
-                         traces.fault_times.shape[1] - FT.shape[1])
-                    )],
-                    axis=1,
-                )
-            Ftier = pad_sentinel(FT, traces.n_faults, 1.0,
-                                 round_pow2=True, min_width=8)
+    with span("repro.engine.pack", timings, "pack_s"):
+        if is_spec:
+            gen, q_eff, f_laws, fp_laws = _spec_draws(traces, mode, q)
+            fp_mean = traces.fp_mean
+            F = P0 = Pft = None
         else:
-            Ftier = None
-    t_pack += _time.monotonic() - t0
+            gen = None
+            F, P0, Pft, Ftier = _host_slabs(traces, q, mode, fmem, any_tl, rng)
 
     devs = _resolve_devices(devices, mesh)
     n_dev = len(devs)
@@ -2001,30 +2042,33 @@ def simulate_batch_jax(
     with jax.enable_x64(x64):
         fdt = np.float64 if x64 else np.float32
         idt = np.int64 if x64 else np.int32
-        tables = None
-        if spec_celled:
-            tables = _cell_tables(
-                n_cells, n_tab, fdt,
-                W, C, D, R, M, T_R, T_P, mode,
-                traces.horizon, traces.window, -1.0,
-                mtbf=traces.mtbf, fp_mean=fp_mean,
-                recall=traces.recall, q_eff=q_eff,
-                fault_laws=f_laws, fp_laws=fp_laws,
-                C2=C2 if (any_tl or any_sil) else None,
-                R2=R2, V=V, fmem=fmem, rho=rho, kv=kv,
-            )
-        acc = None
-        if not want_lanes:
-            # per-cell sums accumulate *on device* across chunks (a
-            # cell's lanes may straddle chunk boundaries): the donated
-            # accumulator is carried through every dispatch and fetched
-            # exactly once after the loop
-            acc = _acc_init(n_seg, fdt, devs)
+        with span("repro.engine.prepare", lanes=L, cells=n_cells):
+            tables = None
+            if spec_celled:
+                tables = _cell_tables(
+                    n_cells, n_tab, fdt,
+                    W, C, D, R, M, T_R, T_P, mode,
+                    traces.horizon, traces.window, -1.0,
+                    mtbf=traces.mtbf, fp_mean=fp_mean,
+                    recall=traces.recall, q_eff=q_eff,
+                    fault_laws=f_laws, fp_laws=fp_laws,
+                    C2=C2 if (any_tl or any_sil) else None,
+                    R2=R2, V=V, fmem=fmem, rho=rho, kv=kv,
+                )
+            acc = None
+            if not want_lanes:
+                # per-cell sums accumulate *on device* across chunks (a
+                # cell's lanes may straddle chunk boundaries): the donated
+                # accumulator is carried through every dispatch and fetched
+                # exactly once after the loop
+                acc = _acc_init(n_seg, fdt, devs)
         outs = []
+        iters = []  # each chunk's outer-loop count, per device
         pend = None  # the chunk in flight: (dispatched pytree, n_real)
         n_chunks = 0
         for lo in range(0, L, chunk):
             sl = slice(lo, min(lo + chunk, L))
+            k = n_chunks
             n_chunks += 1
             # migration-free (and two-level-free, silent-free) chunks
             # compile a specialized step with none of that family's state
@@ -2037,55 +2081,65 @@ def simulate_batch_jax(
                 devs, gen, gathered, n_seg, stats=not want_lanes,
                 has_two_level=has_tl, has_silent=has_sil,
             )
-            t0 = _time.monotonic()
-            if spec_celled:
-                consts, state = _pack_chunk_spec_cells(
-                    tables, traces, cidx_g, n_cells,
-                    sl, n_pad, fdt, idt,
-                )
-            elif is_spec:
-                consts, state = _pack_chunk_spec(
-                    traces, fp_mean, q_eff, sl, n_pad, fdt, idt,
-                    W, C, D, R, M, T_R, T_P, mode,
-                    f_laws=f_laws, fp_laws=fp_laws,
-                    tl=tl_extra if has_tl else None,
-                    sil=sil_extra if has_sil else None,
-                )
-            else:
-                consts, state = _pack_chunk(
-                    has_mig, sl, n_pad, fdt, idt,
-                    W, C, D, R, M, T_R, T_P, mode, F, P0, Pft,
-                    traces.horizon, traces.window,
-                    cidx=cidx_g if celled else None, pad_cell=n_cells,
-                    tl=tl_extra if has_tl else None,
-                    sil=sil_extra if has_sil else None,
-                    Ftier=Ftier if has_tl else None,
-                )
-            t_pack += _time.monotonic() - t0
-            t0 = _time.monotonic()
+            with span("repro.engine.pack", timings, "pack_s", chunk=k):
+                if spec_celled:
+                    consts, state = _pack_chunk_spec_cells(
+                        tables, traces, cidx_g, n_cells,
+                        sl, n_pad, fdt, idt,
+                    )
+                elif is_spec:
+                    consts, state = _pack_chunk_spec(
+                        traces, fp_mean, q_eff, sl, n_pad, fdt, idt,
+                        W, C, D, R, M, T_R, T_P, mode,
+                        f_laws=f_laws, fp_laws=fp_laws,
+                        tl=tl_extra if has_tl else None,
+                        sil=sil_extra if has_sil else None,
+                    )
+                else:
+                    consts, state = _pack_chunk(
+                        has_mig, sl, n_pad, fdt, idt,
+                        W, C, D, R, M, T_R, T_P, mode, F, P0, Pft,
+                        traces.horizon, traces.window,
+                        cidx=cidx_g if celled else None, pad_cell=n_cells,
+                        tl=tl_extra if has_tl else None,
+                        sil=sil_extra if has_sil else None,
+                        Ftier=Ftier if has_tl else None,
+                    )
+            arrays = (*consts.values(), *state.values())
+            with span(
+                "repro.engine.dispatch", timings, "dispatch_s", chunk=k,
+                arrays=len(arrays), bytes=sum(a.nbytes for a in arrays),
+            ):
+                if want_lanes:
+                    disp = _dispatch(runner, devs, consts, state)
+                else:
+                    acc, it = _dispatch(runner, devs, consts, state, acc)
+                    # the count's copy starts as its chunk ends
+                    it.copy_to_host_async()  # repro-lint: disable=host-sync
+                    iters.append(it)
             if want_lanes:
-                disp = _dispatch(runner, devs, consts, state)
-                t_dispatch += _time.monotonic() - t0
                 if pend is not None:  # fetch one chunk behind the dispatch
-                    t0 = _time.monotonic()
-                    outs.append(_fetch(*pend))
-                    t_fetch += _time.monotonic() - t0
+                    outs.append(_collect(timings, *pend))
                 pend = (disp, sl.stop - sl.start)
-            else:
-                acc = _dispatch(runner, devs, consts, state, acc)
-                t_dispatch += _time.monotonic() - t0
-        t0 = _time.monotonic()
         if want_lanes:
-            outs.append(_fetch(*pend))
+            outs.append(_collect(timings, *pend))
+            iters = [o.pop("_iters") for o in outs]
         else:
-            # designed D2H point: one O(cells) stats matrix per run
-            cs = np.asarray(jax.device_get(acc), np.float64)  # repro-lint: disable=host-sync
-        t_fetch += _time.monotonic() - t0
+            # queued before the wait, the copy starts as the last chunk
+            # ends: the wait adds no sync
+            acc.copy_to_host_async()  # repro-lint: disable=host-sync
+            with span("repro.engine.wait", timings, "fetch_s"):
+                acc.block_until_ready()  # repro-lint: disable=host-sync
+            with span("repro.engine.fetch", timings, "fetch_s"):
+                # designed D2H point: one O(cells) stats matrix per run,
+                # fetched with every chunk's loop count
+                cs, iters = jax.device_get((acc, iters))  # repro-lint: disable=host-sync
+            cs = np.asarray(cs, np.float64)
     LAST_TIMINGS.clear()
     LAST_TIMINGS.update(
-        trace_mode="device" if is_spec else "host",
-        pack_s=t_pack, dispatch_s=t_dispatch, fetch_s=t_fetch,
-        n_chunks=n_chunks, precision=precision,
+        trace_mode="device" if is_spec else "host", n_chunks=n_chunks,
+        loop_iters=np.asarray(iters, np.int64).reshape(n_chunks, -1),
+        precision=precision, **timings,
         pallas=("interpret" if interpret else "compiled") if use_pallas else "off",
     )
     if not want_lanes:

@@ -87,6 +87,7 @@ from ..core.events import (
     make_trace_spec,
 )
 from ..core.simulator import simulate
+from ..core.spans import span
 from .grid import CellResult, ExperimentCell, GridSpec, SweepResult
 
 __all__ = ["run_grid", "run_cells", "FusedLayout", "build_fused_layout"]
@@ -470,6 +471,21 @@ def run_grid(
         raise ValueError(
             "collect='stats' requires dispatch='fused' or 'perfamily'"
         )
+    with span(
+        "repro.run_grid", cells=len(grid.cells), lanes=grid.n_lanes,
+        trace_mode=trace_mode,
+    ):
+        return _run_grid(
+            grid, engine, chunk_lanes, devices, mesh, trace_mode, dispatch,
+            collect,
+        )
+
+
+def _run_grid(
+    grid: GridSpec, engine, chunk_lanes, devices, mesh, trace_mode,
+    dispatch, collect,
+) -> SweepResult:
+    """:func:`run_grid` past its checks."""
     t0 = time.monotonic()
     if engine == "legacy":
         cells = []
@@ -490,7 +506,8 @@ def run_grid(
             grid=grid, cells=cells, engine=engine,
             wall_time_s=time.monotonic() - t0, dispatch=dispatch,
         )
-    layout = build_fused_layout(grid, trace_mode)
+    with span("repro.run_grid.layout"):
+        layout = build_fused_layout(grid, trace_mode)
     groups, cell_order = layout.groups, layout.cell_order
     runs_o, offs, specs = layout.runs_o, layout.offs, layout.specs
     work_c, plats_c = layout.work_c, layout.plats_c
@@ -501,12 +518,8 @@ def run_grid(
         traces = layout.host_traces()
 
     lane_parts: List[Dict[str, np.ndarray]] = []
-    stats_rows: List[CellResult] = []
-
-    def _stats_from(sums, first_pos: int):
-        for i in range(sums.n_cells):
-            ci = cell_order[first_pos + i]
-            stats_rows.append(_stats_cell_result(grid.cells[ci], sums, i))
+    # (CellSums, position of its first cell in cell_order)
+    stats_parts: List[Tuple[object, int]] = []
 
     if dispatch == "percell":
         # one engine call per cell: same traces/streams as the fused
@@ -572,14 +585,15 @@ def run_grid(
             # into a single cell-indexed spec whose failure laws ride
             # the cell tables through the law-indexed sampler — one
             # compiled executable per grid *shape*, not per family
-            spec = TraceSpec.concat_cells(specs)
+            with span("repro.run_grid.layout"):
+                spec = TraceSpec.concat_cells(specs)
             res = simulate_batch_jax(
                 work_c, plats_c, strats_c, spec,
                 chunk=chunk_lanes, devices=devices, mesh=mesh,
                 collect=collect,
             )
             if collect == "stats":
-                _stats_from(res, 0)
+                stats_parts.append((res, 0))
             else:
                 lane_parts.append(_lane_arrays(res))
         else:
@@ -600,7 +614,7 @@ def run_grid(
                     collect=collect,
                 )
                 if collect == "stats":
-                    _stats_from(res, a)
+                    stats_parts.append((res, a))
                 else:
                     lane_parts.append(_lane_arrays(res))
                 pos = b
@@ -616,7 +630,7 @@ def run_grid(
             cell_index=cidx, collect=collect,
         )
         if collect == "stats":
-            _stats_from(res, 0)
+            stats_parts.append((res, 0))
         else:
             lane_parts.append(_lane_arrays(res))
     elif engine == "batch":
@@ -645,28 +659,39 @@ def run_grid(
         ]
         lane_parts.append(_scalar_lane_arrays(outs))
 
-    cells: List[Optional[CellResult]] = [None] * len(grid.cells)
-    if collect == "stats":
-        for k, cr in enumerate(stats_rows):
-            cells[cell_order[k]] = cr
-    else:
-        lanes = _cat_lane_arrays(lane_parts)
-        for k, ci in enumerate(cell_order):
-            sl = slice(int(offs[k]), int(offs[k + 1]))
-            cells[ci] = CellResult(
-                cell=grid.cells[ci],
-                waste=lanes["waste"][sl],
-                makespan=lanes["makespan"][sl],
-                n_faults=lanes["n_faults"][sl],
-                n_proactive_ckpts=lanes["n_proactive_ckpts"][sl],
-                n_regular_ckpts=lanes["n_regular_ckpts"][sl],
-                n_migrations=lanes["n_migrations"][sl],
-                n_exhausted=int(np.count_nonzero(lanes["trace_exhausted"][sl])),
-            )
+    with span("repro.run_grid.results"):
+        cells = _cell_results(
+            grid, cell_order, offs, collect, lane_parts, stats_parts
+        )
     return SweepResult(
         grid=grid, cells=cells, engine=engine,
         wall_time_s=time.monotonic() - t0, dispatch=dispatch, collect=collect,
     )
+
+
+def _cell_results(grid, cell_order, offs, collect, lane_parts, stats_parts):
+    """The sweep's :class:`CellResult` rows, in ``grid.cells`` order."""
+    cells: List[Optional[CellResult]] = [None] * len(grid.cells)
+    if collect == "stats":
+        for sums, first in stats_parts:
+            for i in range(sums.n_cells):
+                ci = cell_order[first + i]
+                cells[ci] = _stats_cell_result(grid.cells[ci], sums, i)
+        return cells
+    lanes = _cat_lane_arrays(lane_parts)
+    for k, ci in enumerate(cell_order):
+        sl = slice(int(offs[k]), int(offs[k + 1]))
+        cells[ci] = CellResult(
+            cell=grid.cells[ci],
+            waste=lanes["waste"][sl],
+            makespan=lanes["makespan"][sl],
+            n_faults=lanes["n_faults"][sl],
+            n_proactive_ckpts=lanes["n_proactive_ckpts"][sl],
+            n_regular_ckpts=lanes["n_regular_ckpts"][sl],
+            n_migrations=lanes["n_migrations"][sl],
+            n_exhausted=int(np.count_nonzero(lanes["trace_exhausted"][sl])),
+        )
+    return cells
 
 
 def run_cells(

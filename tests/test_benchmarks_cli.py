@@ -133,11 +133,14 @@ def test_check_regression_cli_passes_on_committed_baselines(tmp_path):
 
 
 def test_run_profile_help_and_unknown_name_precedence():
-    """--profile parses; --only validation still fails fast before any
-    module import even when --profile is passed."""
-    proc = _run_cli("--only", "nope", "--profile")
+    """--only validation fails fast before any module import, and the
+    removed --profile option (a trace nothing read) is refused."""
+    proc = _run_cli("--only", "nope")
     assert proc.returncode != 0
     assert "nope" in proc.stderr + proc.stdout
+    proc = _run_cli("--only", "sim_tables", "--profile")
+    assert proc.returncode == 2
+    assert "--profile" in proc.stderr
 
 
 def test_compare_flags_device_trace_floor():
