@@ -281,8 +281,8 @@ def _spy_dispatch(captures: list, passthrough: bool):
 
     ``passthrough=False`` raises :class:`_AuditDone` after the first
     capture (lanes mode: nothing fabricates per-lane results);
-    ``passthrough=True`` returns the accumulator untouched (and a zero
-    loop count) so the chunk loop — and a whole ``run_grid`` sweep —
+    ``passthrough=True`` returns the accumulator untouched (and zero
+    loop counts) so the chunk loop — and a whole ``run_grid`` sweep —
     completes without ever executing a compiled program."""
     from repro.core import jax_sim
 
@@ -293,7 +293,7 @@ def _spy_dispatch(captures: list, passthrough: bool):
         if passthrough and acc:
             import jax
 
-            return acc[0], jax.device_put(np.int32(0))
+            return acc[0], jax.device_put(np.zeros(2, np.int32))
         raise _AuditDone
 
     jax_sim._dispatch = spy

@@ -23,11 +23,16 @@ jit-compiles to a single XLA while-loop, unlocking Monte-Carlo campaigns
   update) because fixed shapes are what lets XLA fuse each iteration
   into a handful of kernels.  Host-side ``chunk`` scheduling recovers
   the lost-work bound (and the memory bound) for very large grids.
-* **Data-dependent inner loops** — skipping predictions whose action
-  point passed, and cascading faults that strike during downtime, are
-  nested ``lax.while_loop``s whose bodies advance *all* affected lanes
-  per pass; they terminate in a few passes since each pass consumes one
-  event per active lane.
+* **Data-dependent inner loops** — two nested ``lax.while_loop``s per
+  iteration, whose bodies advance *all* affected lanes per pass.  The
+  cascade of faults that strike during downtime is one.  The other is
+  the *prediction walk*, one flat loop after the pop: a lane seeks at
+  most one prediction stream at a time (the next visible true positive
+  or the next visible false prediction), takes one draw of it per pass,
+  and once it seeks nothing re-checks whether its merged head's action
+  point has passed, and if so seeks that head's stream.  So the pop's
+  refill and the next iteration's skips share one loop, whose trip count
+  is the draws of its busiest lane (``LAST_TIMINGS["walk_passes"]``).
 * **Pallas hot step** — the masked primitive execution (fault check +
   work/idle/checkpoint update) is the dense elementwise block run every
   iteration; it executes as a Pallas kernel
@@ -161,7 +166,7 @@ __all__ = [
 
 #: what the most recent :func:`simulate_batch_jax` call did:
 #: {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks",
-#: "loop_iters"}, plus what the call resolved: ``precision``
+#: "loop_iters", "walk_passes"}, plus what the call resolved: ``precision``
 #: ("x64"/"x32") and ``pallas`` ("compiled", "interpret" or "off").
 #: The seconds are the host durations of the call's spans
 #: (:mod:`repro.core.spans`): ``pack_s`` the ``repro.engine.pack`` spans,
@@ -171,7 +176,10 @@ __all__ = [
 #: ``repro.engine.fetch`` spans, the device wait + D2H copies.
 #: ``loop_iters`` is an int64 ``(n_chunks, n_devices)`` array: the outer
 #: while loop's iterations for each chunk on each device, fetched with
-#: the results.  Benchmarks read it to attribute end-to-end time.
+#: the results.  ``walk_passes`` has the same shape: the passes of the
+#: prediction walk (device trace mode; 0 for host traces), summed over
+#: the chunk's outer iterations (the priming walk before the loop is not
+#: counted).  Benchmarks read both to attribute end-to-end time.
 LAST_TIMINGS: dict = {}
 
 #: lane-count granularity: 8 f32 sublanes x 128 lanes, the Pallas tile
@@ -322,71 +330,108 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
                 kind=f_kind, param=f_param, law=f_law, lp=f_lp,
             )
 
-        def adv_fp(m, ctr, tm):
-            return stream_advance(
-                m, ctr, tm, fp_key, fp_mean, horizon,
+        def pick(stp, a, b):
+            # per lane: the fault stream's value (a key, a mean, law
+            # slots or None) where stp, else the false-prediction stream's
+            return jax.tree.map(partial(jnp.where, stp), a, b)
+
+        def adv_either(stp, act, ctr, tm):
+            """One step of the stream each ``act`` lane seeks: the fault
+            stream where ``stp``, else the false-prediction stream.  One
+            counter draw per lane (a draw is a pure function of key and
+            counter, so it has the bits of that stream's own step); the
+            gap transform runs once where both streams share a law."""
+            key, mean = pick(stp, fg_key, fp_key), pick(stp, mtbf, fp_mean)
+            if f_kind == fp_kind and (f_kind == "indexed" or f_param == fp_param):
+                return stream_advance(
+                    act, ctr, tm, key, mean, horizon, kind=f_kind,
+                    param=f_param, law=pick(stp, f_law, fp_law),
+                    lp=pick(stp, f_lp, fp_lp),
+                )
+            f_ctr, f_tm = stream_advance(
+                act, ctr, tm, key, mean, horizon,
+                kind=f_kind, param=f_param, law=f_law, lp=f_lp,
+            )
+            p_ctr, p_tm = stream_advance(
+                act, ctr, tm, key, mean, horizon,
                 kind=fp_kind, param=fp_param, law=fp_law, lp=fp_lp,
             )
+            return pick(stp, (f_ctr, f_tm), (p_ctr, p_tm))
 
-        def tp_consume(m, la_ctr, la_time, tp_t0, tp_ft, tp_ctr):
-            """Advance the lookahead fault cursor until the pending-TP
-            slot holds the next *visible* true positive (or the stream
-            dies at the horizon).  Advance-then-check: each pass draws
-            one fault gap + the fused (coin, offset) pair per active
-            lane, terminating in ~1/recall expected passes."""
+        def walk(stp, sfp, cur, mn, t):
+            """Advance the prediction streams in one flat loop.
+
+            A lane seeks at most one stream at a time: the next *visible*
+            true positive (``stp``: the lookahead fault cursor walks until
+            the recall coin, and with fractional trust the trust coin,
+            admit a fault, or the stream dies at the horizon) or the next
+            visible false prediction (``sfp``).  Each pass every seeking
+            lane takes one step of its stream.  A lane of ``mn`` that
+            seeks nothing then re-checks the skip rule
+            (its merged head's action point has passed ``t``) and starts
+            seeking the head's stream (ties to the TP).  So a lane
+            consumes its streams in the same order, draw for draw, as a
+            separate loop per stream would, and the loop runs as many
+            passes as its busiest lane has draws.  Returns the cursors
+            and the number of passes."""
+
+            def skip(stp, sfp, tp_t0, fp_time):
+                adv = mn & ~(stp | sfp) & (
+                    jnp.minimum(tp_t0, fp_time) - lead_act < t
+                )
+                use_tp = tp_t0 <= fp_time
+                return stp | (adv & use_tp), sfp | (adv & ~use_tp)
 
             def cond(c):
-                return jnp.any(c[0])
+                return jnp.any(c[1] | c[2])
 
             def body(c):
-                act, ctr, tm, t0, ft, tc = c
-                ctr, tm = adv_fault(act, ctr, tm)
+                (n, stp, sfp, la_ctr, la_time, tp_t0, tp_ft, tp_ctr,
+                 fp_ctr, fp_time) = c
+                ctr, tm = adv_either(
+                    stp, stp | sfp,
+                    *pick(stp, (la_ctr, la_time), (fp_ctr, fp_time)),
+                )
                 u_coin, u_off = counter_uniform2(tc_key, ctr, fdt)
+                if frac_q:
+                    trusted = counter_uniform(
+                        pick(stp, tt_key, ft_key), ctr, fdt
+                    ) < q_eff
+                alive = jnp.isfinite(tm)
+                # a TP step: the slot takes the first visible fault
+                la_ctr = jnp.where(stp, ctr, la_ctr)
+                la_time = jnp.where(stp, tm, la_time)
                 vis = u_coin < recall
                 if frac_q:
-                    vis &= counter_uniform(tt_key, ctr, fdt) < q_eff
-                alive = jnp.isfinite(tm)
-                good = act & vis & alive
-                t0 = jnp.where(
-                    good, jnp.maximum(0.0, tm - u_off * window), t0
+                    vis &= trusted
+                good = stp & vis & alive
+                tp_t0 = jnp.where(
+                    good, jnp.maximum(0.0, tm - u_off * window), tp_t0
                 )
-                ft = jnp.where(good, tm, ft)
-                tc = jnp.where(good, ctr, tc)
-                dead = act & ~alive
-                t0 = jnp.where(dead, inf, t0)
-                ft = jnp.where(dead, nan, ft)
-                act = act & ~(good | dead)
-                return act, ctr, tm, t0, ft, tc
+                tp_ft = jnp.where(good, tm, tp_ft)
+                tp_ctr = jnp.where(good, ctr, tp_ctr)
+                dead = stp & ~alive
+                tp_t0 = jnp.where(dead, inf, tp_t0)
+                tp_ft = jnp.where(dead, nan, tp_ft)
+                # an FP step: with fractional trust the stream is thinned
+                # by per-event trust coins
+                fp_ctr = jnp.where(sfp, ctr, fp_ctr)
+                fp_time = jnp.where(sfp, tm, fp_time)
+                # (without fractional trust every false prediction is seen)
+                sfp = sfp & ~trusted & alive if frac_q else jnp.zeros_like(sfp)
+                stp = stp & ~(good | dead)
+                stp, sfp = skip(stp, sfp, tp_t0, fp_time)
+                return (n + 1, stp, sfp, la_ctr, la_time, tp_t0, tp_ft,
+                        tp_ctr, fp_ctr, fp_time)
 
-            _, la_ctr, la_time, tp_t0, tp_ft, tp_ctr = lax.while_loop(
-                cond, body, (m, la_ctr, la_time, tp_t0, tp_ft, tp_ctr)
+            stp, sfp = skip(stp, sfp, cur[2], cur[6])
+            n, _, _, *cur = lax.while_loop(
+                cond, body, (jnp.int32(0), stp, sfp, *cur)
             )
-            return la_ctr, la_time, tp_t0, tp_ft, tp_ctr
-
-        def fp_consume(m, fp_ctr, fp_time):
-            """Advance to the next false prediction; with fractional
-            trust the stream is thinned by per-event trust coins."""
-
-            def cond(c):
-                return jnp.any(c[0])
-
-            def body(c):
-                act, ctr, tm = c
-                ctr, tm = adv_fp(act, ctr, tm)
-                if frac_q:
-                    vis = counter_uniform(ft_key, ctr, fdt) < q_eff
-                else:
-                    vis = jnp.ones_like(act)
-                act = act & ~vis & jnp.isfinite(tm)
-                return act, ctr, tm
-
-            _, fp_ctr, fp_time = lax.while_loop(
-                cond, body, (m, fp_ctr, fp_time)
-            )
-            return fp_ctr, fp_time
+            return tuple(cur), n
 
     def step(carry):
-        it, st = carry
+        it, nw, st = carry
         t = st["t"]
         saved, unsaved = st["saved"], st["unsaved"]
         period_work, na_saved = st["period_work"], st["na_saved"]
@@ -440,33 +485,9 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
         mn = phase == B._PH_MAIN
 
         if device_gen:
-            # skip predictions whose action point passed: consume from
-            # the merged (pending-TP, next-FP) head instead of a cursor
-            def p_cond(c):
-                tp_t0_, fp_time_ = c[2], c[6]
-                head = jnp.minimum(tp_t0_, fp_time_)
-                return jnp.any(mn & (head - lead_act < t))
-
-            def p_body(c):
-                la_ctr_, la_time_, tp_t0_, tp_ft_, tp_ctr_, fp_ctr_, fp_time_ = c
-                head = jnp.minimum(tp_t0_, fp_time_)
-                adv = mn & (head - lead_act < t)
-                use_tp = adv & (tp_t0_ <= fp_time_)
-                la_ctr_, la_time_, tp_t0_, tp_ft_, tp_ctr_ = tp_consume(
-                    use_tp, la_ctr_, la_time_, tp_t0_, tp_ft_, tp_ctr_
-                )
-                fp_ctr_, fp_time_ = fp_consume(
-                    adv & ~use_tp, fp_ctr_, fp_time_
-                )
-                return (la_ctr_, la_time_, tp_t0_, tp_ft_, tp_ctr_,
-                        fp_ctr_, fp_time_)
-
-            (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time) = (
-                lax.while_loop(
-                    p_cond, p_body,
-                    (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time),
-                )
-            )
+            # the merged (pending-TP, next-FP) head: the previous
+            # iteration's walk (or the priming) already skipped every
+            # prediction whose action point passed
             na = jnp.minimum(tp_t0, fp_time) - lead_act
         else:
             def p_cond(pi_):  # skip predictions whose action point passed
@@ -870,57 +891,32 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
         ckr = cmask & (cont == B._C_CKPTREG)
 
         if device_gen:
-            def _pop(args):
-                # pop the merged-head prediction into the episode
-                # registers and refill the consumed cursor; for
-                # _C_CKPTREG (action point fell inside the regular
-                # checkpoint) enter the episode only if the window start
-                # is still current
-                if has_migration:
-                    (ep_t0, ep_ft, ep_fctr, ep_end, la_ctr, la_time,
-                     tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time, phase) = args
-                else:
-                    (ep_t0, ep_end, la_ctr, la_time,
-                     tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time, phase) = args
-                p0v = jnp.minimum(tp_t0, fp_time)
-                takep = ckr & (na_saved <= t) & jnp.isfinite(p0v)
-                good = takep & (p0v >= t - 1e-9)
-                pop = popm | takep
-                use_tp = pop & (tp_t0 <= fp_time)
-                ep_t0 = jnp.where(pop, p0v, ep_t0)
-                ep_end = jnp.where(pop, p0v + window, ep_end)
-                phase = jnp.where(popm | good, B._PH_EP_START, phase)
-                if has_migration:
-                    ep_ft = jnp.where(
-                        pop, jnp.where(use_tp, tp_ft, nan), ep_ft
-                    )
-                    ep_fctr = jnp.where(
-                        pop, jnp.where(use_tp, tp_ctr, -1), ep_fctr
-                    )
-                la_ctr, la_time, tp_t0, tp_ft, tp_ctr = tp_consume(
-                    use_tp, la_ctr, la_time, tp_t0, tp_ft, tp_ctr
-                )
-                fp_ctr, fp_time = fp_consume(pop & ~use_tp, fp_ctr, fp_time)
-                if has_migration:
-                    return (ep_t0, ep_ft, ep_fctr, ep_end, la_ctr, la_time,
-                            tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time, phase)
-                return (ep_t0, ep_end, la_ctr, la_time,
-                        tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time, phase)
-
+            # pop the merged-head prediction into the episode registers;
+            # for _C_CKPTREG (action point fell inside the regular
+            # checkpoint) enter the episode only if the window start is
+            # still current.  A few elementwise merges, so no cond.
+            p0v = jnp.minimum(tp_t0, fp_time)
+            takep = ckr & (na_saved <= t) & jnp.isfinite(p0v)
+            good = takep & (p0v >= t - 1e-9)
+            pop = popm | takep
+            use_tp = pop & (tp_t0 <= fp_time)
+            ep_t0 = jnp.where(pop, p0v, ep_t0)
+            ep_end = jnp.where(pop, p0v + window, ep_end)
+            phase = jnp.where(popm | good, B._PH_EP_START, phase)
             if has_migration:
-                (ep_t0, ep_ft, ep_fctr, ep_end, la_ctr, la_time, tp_t0,
-                 tp_ft, tp_ctr, fp_ctr, fp_time, phase) = lax.cond(
-                    jnp.any(popm | ckr), _pop, lambda a: a,
-                    (ep_t0, ep_ft, ep_fctr, ep_end, la_ctr, la_time,
-                     tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time, phase),
+                ep_ft = jnp.where(pop, jnp.where(use_tp, tp_ft, nan), ep_ft)
+                ep_fctr = jnp.where(
+                    pop, jnp.where(use_tp, tp_ctr, -1), ep_fctr
                 )
-            else:
-                (ep_t0, ep_end, la_ctr, la_time, tp_t0, tp_ft, tp_ctr,
-                 fp_ctr, fp_time, phase) = lax.cond(
-                    jnp.any(popm | ckr), _pop, lambda a: a,
-                    (ep_t0, ep_end, la_ctr, la_time, tp_t0, tp_ft, tp_ctr,
-                     fp_ctr, fp_time, phase),
-                )
+            # one walk: refill the popped stream, then skip, for the next
+            # iteration, every prediction whose action point has passed
+            # (nothing reads the cursors before that iteration's na)
+            (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time), n = walk(
+                use_tp, pop & ~use_tp,
+                (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time),
+                phase == B._PH_MAIN, t,
+            )
+            nw = nw + n
         else:
             def _pop(args):
                 # pop the prediction into the episode registers; for
@@ -989,30 +985,33 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             if has_migration:
                 st["ep_ft"] = ep_ft
                 st["Fcancel"] = Fcancel
-        return it + 1, st
+        return it + 1, nw, st
 
     def cond(carry):
-        it, st = carry
+        it, _, st = carry
         return jnp.any(st["phase"] != B._PH_DONE) & (it < max_iters)
 
     if device_gen:
         # prime the cursors: first strike fault, first visible TP (walks
-        # the lookahead stream), first visible false prediction.  Inert
-        # (padding) lanes never activate a stream.
+        # the lookahead stream), first visible false prediction, then the
+        # first iteration's skip.  Inert (padding) lanes never activate a
+        # stream.
         state = dict(state)
         live = state["phase"] != B._PH_DONE
         neg1 = jnp.full_like(state["phase"], -1)
         zf = jnp.zeros_like(horizon)
+        no_lane = jnp.zeros_like(live)
         sf_ctr, sf_time = adv_fault(live, neg1, zf)
         pvis = live & (q_eff > 0.0)
-        la_ctr, la_time, tp_t0, tp_ft, tp_ctr = tp_consume(
-            pvis & (recall > 0.0), neg1, zf,
-            jnp.full_like(horizon, jnp.inf), jnp.full_like(horizon, jnp.nan),
-            neg1,
-        )
         fp_act = pvis & jnp.isfinite(fp_mean)
-        fp_ctr, fp_time = fp_consume(fp_act, neg1, zf)
-        fp_time = jnp.where(fp_act, fp_time, jnp.asarray(jnp.inf, fdt))
+        cur = (neg1, zf, jnp.full_like(horizon, jnp.inf),
+               jnp.full_like(horizon, jnp.nan), neg1,
+               neg1, jnp.where(fp_act, zf, inf))
+        cur, _ = walk(pvis & (recall > 0.0), no_lane, cur, no_lane,
+                      state["t"])
+        cur, _ = walk(no_lane, fp_act, cur, state["phase"] == B._PH_MAIN,
+                      state["t"])
+        la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time = cur
         state.update(
             sf_ctr=sf_ctr, sf_time=sf_time, la_ctr=la_ctr, la_time=la_time,
             tp_t0=tp_t0, tp_ft=tp_ft, tp_ctr=tp_ctr,
@@ -1042,9 +1041,13 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
         state.setdefault("ck_v", zt)
         state.setdefault("corrupt", jnp.full_like(state["t"], jnp.inf))
 
-    n_it, final = lax.while_loop(cond, step, (jnp.int32(0), state))
-    # the outer loop's iteration count rides out beside the results
-    final = dict(final); final["_iters"] = n_it
+    n_it, n_walk, final = lax.while_loop(
+        cond, step, (jnp.int32(0), jnp.int32(0), state)
+    )
+    # the outer loop's iteration count and the walk's passes ride out
+    # beside the results
+    final = dict(final)
+    final["_counts"] = jnp.stack([n_it, n_walk])
     if n_seg:
         # per-cell segment reduction on device: one (n_seg, 13) matrix of
         # Monte-Carlo sums per chunk instead of O(lanes) result fetches.
@@ -1206,13 +1209,13 @@ class _ShardedRunner:
             cspec = {k: self._pspec(k) for k in consts}
             sspec = {k: self._pspec(k) for k in state}
             step = self._step
-            # each device's outer-loop count leaves as one row of
-            # ``_iters``: (n_dev,) after the gather
+            # each device's (outer-loop count, walk passes) leaves as one
+            # row of ``_counts``: (n_dev, 2) after the gather
             if self._stats:
                 def body(c, s, a):
                     final = step(c, s)
                     cs = jax.lax.psum(final["cell_sums"], "lanes")
-                    return _fold(a, cs), final["_iters"].reshape(1)
+                    return _fold(a, cs), final["_counts"].reshape(1, 2)
 
                 fn = jax.jit(
                     jax.shard_map(
@@ -1227,14 +1230,14 @@ class _ShardedRunner:
                 def body(c, s):
                     final = step(c, s)
                     out = {k: final[k] for k in _OUT_KEYS}
-                    out["_iters"] = final["_iters"].reshape(1)
+                    out["_counts"] = final["_counts"].reshape(1, 2)
                     return out
 
                 fn = jax.jit(
                     jax.shard_map(
                         body, mesh=self.mesh,
                         in_specs=(cspec, sspec),
-                        out_specs={k: P("lanes") for k in _OUT_KEYS + ("_iters",)},
+                        out_specs={k: P("lanes") for k in _OUT_KEYS + ("_counts",)},
                         check_vma=False,
                     ),
                     donate_argnums=(1,),
@@ -1285,7 +1288,7 @@ def _get_runner(
         # accumulator: the O(lanes) state never crosses the host boundary
         def run_stats(consts, state, acc):
             final = step(consts, state)
-            return _fold(acc, final["cell_sums"]), final["_iters"]
+            return _fold(acc, final["cell_sums"]), final["_counts"]
 
         runner = jax.jit(run_stats, donate_argnums=(1, 2))
     else:
@@ -1713,9 +1716,10 @@ def _acc_init(n_seg: int, fdt, devs):
 
 def _fetch(final, n_real: int):
     """Pull one dispatched chunk's per-lane results back to the host,
-    with its outer-loop count per device under ``"_iters"``."""
+    with its (outer-loop count, walk passes) per device under
+    ``"_counts"``."""
     out = {k: np.asarray(final[k])[:n_real] for k in _OUT_KEYS}
-    out["_iters"] = np.asarray(final["_iters"]).reshape(-1)
+    out["_counts"] = np.asarray(final["_counts"]).reshape(-1, 2)
     n_open = int((out.pop("phase") != B._PH_DONE).sum())
     if n_open:
         raise RuntimeError(
@@ -1730,7 +1734,7 @@ def _collect(timings: dict, final, n_real: int):
     to ``timings["fetch_s"]``.  The copies are queued before the wait,
     so they start as the chunk ends: the wait adds no sync."""
     # the engine's one designed D2H point for per-lane results
-    for k in _OUT_KEYS + ("_iters",):
+    for k in _OUT_KEYS + ("_counts",):
         final[k].copy_to_host_async()  # repro-lint: disable=host-sync
     with span("repro.engine.wait", timings, "fetch_s"):
         final["t"].block_until_ready()  # repro-lint: disable=host-sync
@@ -2063,7 +2067,7 @@ def simulate_batch_jax(
                 # exactly once after the loop
                 acc = _acc_init(n_seg, fdt, devs)
         outs = []
-        iters = []  # each chunk's outer-loop count, per device
+        counts = []  # each chunk's (outer-loop count, walk passes), per device
         pend = None  # the chunk in flight: (dispatched pytree, n_real)
         n_chunks = 0
         for lo in range(0, L, chunk):
@@ -2113,17 +2117,17 @@ def simulate_batch_jax(
                 if want_lanes:
                     disp = _dispatch(runner, devs, consts, state)
                 else:
-                    acc, it = _dispatch(runner, devs, consts, state, acc)
-                    # the count's copy starts as its chunk ends
-                    it.copy_to_host_async()  # repro-lint: disable=host-sync
-                    iters.append(it)
+                    acc, cnt = _dispatch(runner, devs, consts, state, acc)
+                    # the counts' copy starts as their chunk ends
+                    cnt.copy_to_host_async()  # repro-lint: disable=host-sync
+                    counts.append(cnt)
             if want_lanes:
                 if pend is not None:  # fetch one chunk behind the dispatch
                     outs.append(_collect(timings, *pend))
                 pend = (disp, sl.stop - sl.start)
         if want_lanes:
             outs.append(_collect(timings, *pend))
-            iters = [o.pop("_iters") for o in outs]
+            counts = [o.pop("_counts") for o in outs]
         else:
             # queued before the wait, the copy starts as the last chunk
             # ends: the wait adds no sync
@@ -2132,13 +2136,14 @@ def simulate_batch_jax(
                 acc.block_until_ready()  # repro-lint: disable=host-sync
             with span("repro.engine.fetch", timings, "fetch_s"):
                 # designed D2H point: one O(cells) stats matrix per run,
-                # fetched with every chunk's loop count
-                cs, iters = jax.device_get((acc, iters))  # repro-lint: disable=host-sync
+                # fetched with every chunk's loop counts
+                cs, counts = jax.device_get((acc, counts))  # repro-lint: disable=host-sync
             cs = np.asarray(cs, np.float64)
+    counts = np.asarray(counts, np.int64).reshape(n_chunks, -1, 2)
     LAST_TIMINGS.clear()
     LAST_TIMINGS.update(
         trace_mode="device" if is_spec else "host", n_chunks=n_chunks,
-        loop_iters=np.asarray(iters, np.int64).reshape(n_chunks, -1),
+        loop_iters=counts[..., 0], walk_passes=counts[..., 1],
         precision=precision, **timings,
         pallas=("interpret" if interpret else "compiled") if use_pallas else "off",
     )

@@ -1,13 +1,15 @@
-"""The program's own spans, kernel scope and loop counter
-(``repro.core.spans``, ``jax_sim.LAST_TIMINGS["loop_iters"]``), on the
-CPU: they leave results bit-identical, keep ``LAST_TIMINGS``'s fields,
-count each chunk's outer loop, reach a recorded trace nested and tagged
-as documented, and add no host transfer."""
+"""The program's own spans, kernel scope and loop counters
+(``repro.core.spans``, ``jax_sim.LAST_TIMINGS["loop_iters"]`` and
+``["walk_passes"]``), on the CPU: they leave results bit-identical, keep
+``LAST_TIMINGS``'s fields, count each chunk's outer loop and prediction
+walk, reach a recorded trace nested and tagged as documented, and add no
+host transfer."""
 
 import glob
 import os
 import subprocess
 import sys
+from dataclasses import replace
 
 import numpy as np
 import pytest
@@ -47,7 +49,7 @@ SPANS = {
 }
 
 TIMING_KEYS = {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks",
-               "loop_iters", "precision", "pallas"}
+               "loop_iters", "walk_passes", "precision", "pallas"}
 
 
 def _cells(n_runs):
@@ -140,9 +142,10 @@ def test_timings_keep_their_fields_and_meaning():
     t = jax_sim.LAST_TIMINGS
     assert set(t) == TIMING_KEYS
     assert t["n_chunks"] == 2  # 120 lanes in chunks of 64
-    assert t["loop_iters"].shape == (2, 1)
-    assert t["loop_iters"].dtype == np.int64
-    assert (t["loop_iters"] > 0).all()
+    for k in ("loop_iters", "walk_passes"):
+        assert t[k].shape == (2, 1)
+        assert t[k].dtype == np.int64
+        assert (t[k] > 0).all()
     for k in ("pack_s", "dispatch_s", "fetch_s"):
         assert t[k] > 0.0
     assert (t["trace_mode"], t["precision"]) == ("device", "x64")
@@ -150,13 +153,15 @@ def test_timings_keep_their_fields_and_meaning():
 
 @pytest.mark.parametrize("collect", ["stats", "lanes"])
 def test_loop_iters_are_each_chunks_count(collect):
-    """``loop_iters[k]`` is chunk ``k``'s outer-loop count: lanes evolve
-    independently, so the same lanes run alone take as many iterations."""
+    """``loop_iters[k]`` is chunk ``k``'s outer-loop count, and
+    ``walk_passes[k]`` its prediction walk's passes: lanes evolve
+    independently, so the same lanes run alone take as many of each."""
     plats, strats, cidx, spec = _cells(CHUNK)
     simulate_batch_jax([WORK] * 3, plats, strats, spec, chunk=CHUNK,
                        collect=collect, use_pallas=False)
     whole = jax_sim.LAST_TIMINGS["loop_iters"]
-    assert whole.shape == (N_CHUNKS, 1)
+    walk = jax_sim.LAST_TIMINGS["walk_passes"]
+    assert whole.shape == walk.shape == (N_CHUNKS, 1)
     lane = spec.expand()
     for k in range(N_CHUNKS):
         rows = np.arange(k * CHUNK, (k + 1) * CHUNK)
@@ -166,6 +171,44 @@ def test_loop_iters_are_each_chunks_count(collect):
             use_pallas=False,
         )
         assert jax_sim.LAST_TIMINGS["loop_iters"].tolist() == [[whole[k, 0]]]
+        assert jax_sim.LAST_TIMINGS["walk_passes"].tolist() == [[walk[k, 0]]]
+
+
+def _walk_passes(strat, pred, trace_mode="device"):
+    from repro.experiments import ExperimentCell, GridSpec, run_grid
+
+    cells = tuple(
+        ExperimentCell(label=f"{strat.name}{i}", work=6 * 86400.0,
+                       platform=PLAT, predictor=pred, strategy=strat)
+        for i in range(2)
+    )
+    cfg = replace(_config("lanes" if trace_mode == "host" else "stats"),
+                  trace_mode=trace_mode)
+    run_grid(GridSpec(cells, n_runs=40, seed=5), cfg)
+    return jax_sim.LAST_TIMINGS["walk_passes"]
+
+
+@pytest.mark.parametrize("strat,pred", [
+    (S.young(PLAT), PRED),
+    (S.Strategy("Distrust", S.young(PLAT).T_R, q=0.0, mode="exact"), PRED),
+    (S.exact_prediction(PLAT, PredictorModel(0.0, 1.0)),
+     PredictorModel(0.0, 1.0)),
+], ids=["young", "distrust", "no-predictions"])
+def test_walk_passes_are_zero_where_no_lane_predicts(strat, pred):
+    """Lanes that trust no prediction, or get none, never walk."""
+    got = _walk_passes(strat, pred)
+    assert got.shape == (2, 1) and got.dtype == np.int64
+    assert (got == 0).all()
+
+
+def test_walk_passes_count_exact_prediction():
+    """ExactPrediction lanes walk at least one pass per prediction they
+    consume, so every chunk counts some; host traces have no walk."""
+    got = _walk_passes(S.exact_prediction(PLAT, PRED), PRED)
+    assert got.shape == (2, 1) and got.dtype == np.int64
+    assert (got > 0).all()
+    host = _walk_passes(S.exact_prediction(PLAT, PRED), PRED, "host")
+    assert (host == 0).all()
 
 
 SHARDED = """
@@ -181,7 +224,8 @@ for collect in ("stats", "lanes"):
     simulate_batch_jax([T.WORK] * 3, plats, strats, spec, chunk=T.CHUNK,
                        devices=2, collect=collect, use_pallas=False)
     two = jax_sim.LAST_TIMINGS["loop_iters"]
-    assert two.shape == (T.N_CHUNKS, 2), two.shape
+    walk = jax_sim.LAST_TIMINGS["walk_passes"]
+    assert two.shape == walk.shape == (T.N_CHUNKS, 2), (two.shape, walk.shape)
     for k in range(T.N_CHUNKS):
         for d in range(2):
             rows = np.arange(k * T.CHUNK + d * half, k * T.CHUNK + (d + 1) * half)
@@ -192,13 +236,16 @@ for collect in ("stats", "lanes"):
             )
             one = jax_sim.LAST_TIMINGS["loop_iters"]
             assert one.tolist() == [[two[k, d]]], (collect, k, d, one, two)
+            one = jax_sim.LAST_TIMINGS["walk_passes"]
+            assert one.tolist() == [[walk[k, d]]], (collect, k, d, one, walk)
 print("LOOP_ITERS_OK")
 """
 
 
 def test_loop_iters_count_per_device_when_sharded():
-    """With 2 forced host devices each chunk reports one count per
-    device: that of its shard's lanes run alone on one device."""
+    """With 2 forced host devices each chunk reports one count (of loop
+    iterations and of walk passes) per device: that of its shard's lanes
+    run alone on one device."""
     here = os.path.dirname(os.path.abspath(__file__))
     env = dict(
         os.environ, JAX_PLATFORMS="cpu",
