@@ -1324,8 +1324,8 @@ class TraceSpec:
         """Replay the counter streams on the host into a
         :class:`BatchTraces` — the exact events the device engine samples
         lazily (fault dates bit-identical in f64; merged predictions
-        time-sorted as in :func:`make_event_traces_batch`, whereas the
-        device cursor consumes true-positive predictions in fault order).
+        time-sorted as in :func:`make_event_traces_batch`, the order the
+        device engine's window-ordered buffer serves them in).
 
         Trust coins (fractional ``q``) are *not* applied here: host
         engines draw trust from their own RNG, so fractional-``q`` runs

@@ -104,34 +104,45 @@ compilation-shape reuse, so multi-million-lane campaigns fit trivially.
 * *strike cursor* ``(sf_ctr, sf_time)`` — the next fault to hit the
   node; refilled by one counter draw when a fault resolves (fused into
   the Pallas hot step) or goes stale during downtime.
-* *lookahead cursor* ``(la_ctr, la_time)`` + *pending-TP slot*
+* *lookahead cursor* ``(la_ctr, la_time)`` + *pending-TP buffer*
   ``(tp_t0, tp_ft, tp_ctr)`` — the fault stream is walked ahead of the
-  strike cursor to find the next *visible* true-positive prediction
-  (recall coin, then trust coin for fractional ``q``); its window
-  position comes from the offset stream.
+  strike cursor to find the *visible* true-positive predictions (recall
+  coin, then trust coin for fractional ``q``); a window's position comes
+  from the offset stream.  The buffer holds each lane's pending TPs in
+  window-start order, ties in fault order — the order of the host
+  generator's stable sort.  The walk inserts every visible TP it draws
+  and walks on until the next fault's date, less one window, reaches the
+  head's window start: no later fault can then open its window first.
+  A call with no window (``has_window`` false) keeps one slot, where
+  the first visible TP is final at once.  With windows a call keeps
+  :data:`WINDOW_TP_SLOTS`; a lane that would need more is reported
+  (``LAST_TIMINGS["tp_overflow"]``, and the call raises), never served
+  out of order.
 * *false-prediction cursor* ``(fp_ctr, fp_time)`` — an independent
   renewal stream at the Section 2.3 false-prediction rate.
-* the merged prediction head is ``min(tp_t0, fp_time)`` (ties to the
-  TP, matching the host generator's stable sort).  True positives are
-  consumed in fault order; when a prediction window exceeds the fault
-  inter-arrival gap the host path's time-sorted merge can order two TPs
-  differently — a distribution-level (not per-trace) difference, which
-  is why device-mode equivalence is statistical for ``window > 0`` and
-  exact for exact-date predictions.
+* the merged prediction head is ``min(tp_t0[0], fp_time)`` (ties to the
+  TP, matching the host generator's stable sort), so predictions are
+  consumed in the host path's order, with or without windows: the two
+  trace modes run the same event sequence per lane.
 * *migration cancel slots* ``(ep_fctr, cancel_ctr[3])`` — the
   vacated-node fault is cancelled by counter index instead of an
-  ``(L, F)`` mask scan.  Cancellations are set in fault order (TPs are
-  consumed in fault order) and retired in fault order (the strike
-  cursor visits indices monotonically), so three slots track pending
-  cancellations exactly; a fourth *simultaneously pending* cancellation
-  (four overlapping migration episodes with undelivered predicted
-  faults) is dropped — beyond-pathological under any paper parameters.
+  ``(L, F)`` mask scan.  With windows TPs are popped in window order, so
+  cancellations are set out of fault order; that is sound because each
+  slot is retired on its own (the strike cursor visits counters
+  monotonically and drops every slot it has passed) and a new
+  cancellation takes any free slot, so the slots hold exactly the
+  pending cancellations whatever order they were set in.  A fourth
+  *simultaneously pending* cancellation (four migration episodes begun
+  whose predicted faults have not yet come) is dropped — beyond
+  pathological at exact dates; with windows as wide as the mean fault
+  gap it is not, so a call with windows reports it as an overflow.
 
 Streams retire at the lane's generation horizon (date ``+inf``), exactly
 like the host generator's ``(0, horizon]`` clipping.  Equivalence with
 the host-generated path is statistical (same laws, different draws);
-:meth:`TraceSpec.materialize` replays the identical streams on the host
-for exactness tests and KS/accounting fidelity checks.
+:meth:`TraceSpec.materialize` replays the identical streams on the host,
+and the NumPy engine on that replay agrees with this engine per lane to
+float rounding (trust ``q`` in {0, 1}), windows included.
 """
 
 from __future__ import annotations
@@ -166,7 +177,8 @@ __all__ = [
 
 #: what the most recent :func:`simulate_batch_jax` call did:
 #: {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks",
-#: "loop_iters", "walk_passes"}, plus what the call resolved: ``precision``
+#: "loop_iters", "walk_passes", "tp_overtakes", "tp_pending_peak",
+#: "tp_overflow"}, plus what the call resolved: ``precision``
 #: ("x64"/"x32") and ``pallas`` ("compiled", "interpret" or "off").
 #: The seconds are the host durations of the call's spans
 #: (:mod:`repro.core.spans`): ``pack_s`` the ``repro.engine.pack`` spans,
@@ -179,11 +191,36 @@ __all__ = [
 #: the results.  ``walk_passes`` has the same shape: the passes of the
 #: prediction walk (device trace mode; 0 for host traces), summed over
 #: the chunk's outer iterations (the priming walk before the loop is not
-#: counted).  Benchmarks read both to attribute end-to-end time.
+#: counted).  The pending-TP buffer's counts have the same shape and are
+#: 0 in a call with no window (one slot, nothing to order):
+#: ``tp_overtakes`` the TPs that entered a lane's buffer ahead of a TP
+#: already pending there (a later fault's window opening first),
+#: ``tp_pending_peak`` the most TPs any lane held pending at once, and
+#: ``tp_overflow`` the lanes that needed more than
+#: :data:`WINDOW_TP_SLOTS`, or more than the three migration cancel
+#: slots (the call then raises).  Benchmarks read them
+#: to attribute end-to-end time.
 LAST_TIMINGS: dict = {}
 
 #: lane-count granularity: 8 f32 sublanes x 128 lanes, the Pallas tile
 LANE_TILE = 1024
+
+#: pending-TP slots per lane in a call with prediction windows (a call
+#: without keeps the one slot).  Sized from the tail of the pending count
+#: at the Section 5 window cell's worst point (2^19 nodes, MTBF 7,524 s,
+#: r = 0.85, I = 6,000 s, r I / mu = 0.68): over 2.55e7 TPs replayed on
+#: the host with this lookahead rule, a TP found 3 or more pending with
+#: it 25 % of the time, 5 or more 0.43 %, 7 or more 2.4e-5, 8 or more
+#: 1.5e-6 and 9 or more 3.9e-8, the tail falling 12-fold a slot and
+#: steepening; so 17 pending (an overflow) is below 1e-15 a TP, against
+#: the ~1e10 TPs a benchmark check draws there.  An overflow is reported
+#: (``LAST_TIMINGS["tp_overflow"]`` and a RuntimeError), never dropped.
+WINDOW_TP_SLOTS = 16
+
+#: counts a chunk reports per device, in order: outer-loop iterations,
+#: walk passes, and (calls with windows) TPs that overtook a pending one,
+#: the most TPs a lane held pending, lanes whose buffer overflowed
+_N_COUNTS = 5
 
 #: per-device lane granularity of the sharded dispatch (the Pallas row
 #: width): small enough that 8-way sharding of a cache-sized CPU chunk
@@ -231,7 +268,7 @@ def default_chunk_lanes(
 
 def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
              has_migration, has_two_level=False, has_silent=False,
-             gen=None, gathered=(), n_seg=0):
+             has_window=False, tp_slots=1, gen=None, gathered=(), n_seg=0):
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -358,6 +395,86 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             )
             return pick(stp, (f_ctr, f_tm), (p_ctr, p_tm))
 
+        if has_window:
+            # the pending-TP buffer: K slots per lane in window-start
+            # order, ties in fault order (the host generator's stable
+            # sort); one (K, L) array per field, the fault date and
+            # counter only where the migration cancel reads them
+            K = tp_slots
+            slots = jnp.arange(K, dtype=jnp.int32)[:, None]
+            buf_fill = (inf, nan, -1) if has_migration else (inf,)
+
+            def buf_insert(buf, good, vals):
+                """Insert each ``good`` lane's TP after every pending one
+                whose window opens no later; returns its slot (K where
+                nothing is inserted) and the buffer."""
+                pos = jnp.where(
+                    good,
+                    jnp.sum(buf[0] <= vals[0], axis=0, dtype=jnp.int32), K,
+                )
+                return pos, tuple(
+                    jnp.where(slots < pos, b, jnp.where(
+                        slots == pos, v, jnp.concatenate([v[None], b[:-1]])
+                    ))
+                    for b, v in zip(buf, vals)
+                )
+
+            def buf_pop(buf, m):
+                """Drop each ``m`` lane's head; the rest move up a slot."""
+                return tuple(
+                    jnp.where(
+                        m, jnp.concatenate([b[1:], jnp.full_like(b[:1], f)]),
+                        b,
+                    )
+                    for b, f in zip(buf, buf_fill)
+                )
+
+            def look(stp, la_time, head, npend, ovf):
+                """Which ``stp`` lanes walk on: every fault not yet drawn
+                comes at or after ``la_time`` and opens its window at or
+                after ``la_time - window`` (the offset is below one window
+                and rounding is monotone), and a tie sorts it after the
+                head, so the head is final once that date reaches its
+                window start.  A lane that must walk on with a full buffer
+                cannot keep its predictions in order: it stops and is
+                marked (``ovf``), never dropping one silently."""
+                need = stp & (la_time - window < head)
+                full = npend >= K
+                return need & ~full, ovf | (need & full)
+
+        def tp_draw(stp, sfp, la_ctr, la_time, fp_ctr, fp_time):
+            """One counter draw of the stream each seeking lane seeks,
+            and the TP side of it: the lookahead cursor moves, and
+            ``good`` marks the lanes that drew a visible true positive."""
+            ctr, tm = adv_either(
+                stp, stp | sfp,
+                *pick(stp, (la_ctr, la_time), (fp_ctr, fp_time)),
+            )
+            u_coin, u_off = counter_uniform2(tc_key, ctr, fdt)
+            trusted = None
+            if frac_q:
+                trusted = counter_uniform(
+                    pick(stp, tt_key, ft_key), ctr, fdt
+                ) < q_eff
+            alive = jnp.isfinite(tm)
+            # a TP step: the lookahead cursor moves
+            la_ctr = jnp.where(stp, ctr, la_ctr)
+            la_time = jnp.where(stp, tm, la_time)
+            vis = u_coin < recall
+            if frac_q:
+                vis &= trusted
+            good = stp & vis & alive
+            return ctr, tm, u_off, trusted, alive, la_ctr, la_time, good
+
+        def fp_draw(sfp, ctr, tm, trusted, alive, fp_ctr, fp_time):
+            """The FP side of a draw: the false-prediction cursor moves;
+            with fractional trust the stream is thinned by per-event
+            trust coins (without it every false prediction is seen)."""
+            fp_ctr = jnp.where(sfp, ctr, fp_ctr)
+            fp_time = jnp.where(sfp, tm, fp_time)
+            sfp = sfp & ~trusted & alive if frac_q else jnp.zeros_like(sfp)
+            return fp_ctr, fp_time, sfp
+
         def walk(stp, sfp, cur, mn, t):
             """Advance the prediction streams in one flat loop.
 
@@ -373,7 +490,13 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             consumes its streams in the same order, draw for draw, as a
             separate loop per stream would, and the loop runs as many
             passes as its busiest lane has draws.  Returns the cursors
-            and the number of passes."""
+            and the number of passes.
+
+            With windows (``has_window``) the pending TPs sit in the
+            window-ordered buffer, and ``stp`` marks the lanes that may
+            walk the fault stream: a lane walks it while its head may not
+            be final (:func:`look`), inserting every visible TP it
+            draws; a skipped TP head leaves the buffer at once."""
 
             def skip(stp, sfp, tp_t0, fp_time):
                 adv = mn & ~(stp | sfp) & (
@@ -388,23 +511,9 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             def body(c):
                 (n, stp, sfp, la_ctr, la_time, tp_t0, tp_ft, tp_ctr,
                  fp_ctr, fp_time) = c
-                ctr, tm = adv_either(
-                    stp, stp | sfp,
-                    *pick(stp, (la_ctr, la_time), (fp_ctr, fp_time)),
+                ctr, tm, u_off, trusted, alive, la_ctr, la_time, good = (
+                    tp_draw(stp, sfp, la_ctr, la_time, fp_ctr, fp_time)
                 )
-                u_coin, u_off = counter_uniform2(tc_key, ctr, fdt)
-                if frac_q:
-                    trusted = counter_uniform(
-                        pick(stp, tt_key, ft_key), ctr, fdt
-                    ) < q_eff
-                alive = jnp.isfinite(tm)
-                # a TP step: the slot takes the first visible fault
-                la_ctr = jnp.where(stp, ctr, la_ctr)
-                la_time = jnp.where(stp, tm, la_time)
-                vis = u_coin < recall
-                if frac_q:
-                    vis &= trusted
-                good = stp & vis & alive
                 tp_t0 = jnp.where(
                     good, jnp.maximum(0.0, tm - u_off * window), tp_t0
                 )
@@ -413,20 +522,73 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
                 dead = stp & ~alive
                 tp_t0 = jnp.where(dead, inf, tp_t0)
                 tp_ft = jnp.where(dead, nan, tp_ft)
-                # an FP step: with fractional trust the stream is thinned
-                # by per-event trust coins
-                fp_ctr = jnp.where(sfp, ctr, fp_ctr)
-                fp_time = jnp.where(sfp, tm, fp_time)
-                # (without fractional trust every false prediction is seen)
-                sfp = sfp & ~trusted & alive if frac_q else jnp.zeros_like(sfp)
+                fp_ctr, fp_time, sfp = fp_draw(
+                    sfp, ctr, tm, trusted, alive, fp_ctr, fp_time
+                )
                 stp = stp & ~(good | dead)
                 stp, sfp = skip(stp, sfp, tp_t0, fp_time)
                 return (n + 1, stp, sfp, la_ctr, la_time, tp_t0, tp_ft,
                         tp_ctr, fp_ctr, fp_time)
 
-            stp, sfp = skip(stp, sfp, cur[2], cur[6])
-            n, _, _, *cur = lax.while_loop(
-                cond, body, (jnp.int32(0), stp, sfp, *cur)
+            if not has_window:
+                stp, sfp = skip(stp, sfp, cur[2], cur[6])
+                n, _, _, *cur = lax.while_loop(
+                    cond, body, (jnp.int32(0), stp, sfp, *cur)
+                )
+                return tuple(cur), n
+
+            def skip_w(stp, sfp, la_time, buf, npend, fp_time):
+                # as skip; a skipped TP head leaves the buffer now, and
+                # the lane walks on where its new head may not be final,
+                # else re-checks the rule on the next pass (``again``)
+                head = buf[0][0]
+                adv = mn & ~(stp | sfp) & (
+                    jnp.minimum(head, fp_time) - lead_act < t
+                )
+                use_tp = head <= fp_time
+                popt = adv & use_tp
+                buf = buf_pop(buf, popt)
+                npend = npend - popt.astype(npend.dtype)
+                seek = popt & (la_time - window < buf[0][0])
+                return (stp | seek, sfp | (adv & ~use_tp), popt & ~seek,
+                        buf, npend)
+
+            def cond_w(c):
+                return jnp.any(c[1] | c[2] | c[3])
+
+            def body_w(c):
+                (n, stp, sfp, _, la_ctr, la_time, buf, npend, fp_ctr,
+                 fp_time, ovt, peak, ovf) = c
+                ctr, tm, u_off, trusted, alive, la_ctr, la_time, good = (
+                    tp_draw(stp, sfp, la_ctr, la_time, fp_ctr, fp_time)
+                )
+                x_t0 = jnp.maximum(0.0, tm - u_off * window)
+                pos, buf = buf_insert(
+                    buf, good, (x_t0, tm, ctr) if has_migration else (x_t0,)
+                )
+                # an overtake: the TP's window opens before a pending one's
+                ovt = ovt + (pos < npend).astype(ovt.dtype)
+                npend = npend + good.astype(npend.dtype)
+                peak = jnp.maximum(peak, npend)
+                fp_ctr, fp_time, sfp = fp_draw(
+                    sfp, ctr, tm, trusted, alive, fp_ctr, fp_time
+                )
+                stp, ovf = look(stp, la_time, buf[0][0], npend, ovf)
+                stp, sfp, again, buf, npend = skip_w(
+                    stp, sfp, la_time, buf, npend, fp_time
+                )
+                return (n + 1, stp, sfp, again, la_ctr, la_time, buf, npend,
+                        fp_ctr, fp_time, ovt, peak, ovf)
+
+            la_ctr, la_time, buf, npend, fp_ctr, fp_time, ovt, peak, ovf = cur
+            stp, ovf = look(stp, la_time, buf[0][0], npend, ovf)
+            stp, sfp, again, buf, npend = skip_w(
+                stp, sfp, la_time, buf, npend, fp_time
+            )
+            n, _, _, _, *cur = lax.while_loop(
+                cond_w, body_w,
+                (jnp.int32(0), stp, sfp, again, la_ctr, la_time, buf, npend,
+                 fp_ctr, fp_time, ovt, peak, ovf),
             )
             return tuple(cur), n
 
@@ -450,7 +612,16 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             fi = pi = None
             sf_ctr, sf_time = st["sf_ctr"], st["sf_time"]
             la_ctr, la_time = st["la_ctr"], st["la_time"]
-            tp_t0, tp_ft, tp_ctr = st["tp_t0"], st["tp_ft"], st["tp_ctr"]
+            if has_window:
+                # the buffer's head stands where the one slot stands
+                buf = st["tp_buf"]
+                tp_t0 = buf[0][0]
+                tp_ft, tp_ctr = (buf[1][0], buf[2][0]) if has_migration else (
+                    None, None)
+                tp_n, tp_ovt = st["tp_n"], st["tp_ovt"]
+                tp_peak, tp_ovf = st["tp_peak"], st["tp_ovf"]
+            else:
+                tp_t0, tp_ft, tp_ctr = st["tp_t0"], st["tp_ft"], st["tp_ctr"]
             fp_ctr, fp_time = st["fp_ctr"], st["fp_time"]
             if has_migration:
                 ep_fctr = st["ep_fctr"]
@@ -566,12 +737,19 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             if device_gen:
                 # cancel by fault-counter index (stored at pop time) —
                 # elementwise merges instead of an (L, F) match scan.
-                # Slots fill in fault order and retire in fault order;
-                # a fourth simultaneously-pending cancel is dropped.
+                # A cancel takes the first free slot, and each slot
+                # retires once the strike cursor passes it, so the fill
+                # order does not matter (windows pop TPs out of fault
+                # order); a fourth simultaneously-pending cancel is
+                # dropped.
                 c0, c1, c2 = cancels
                 f0 = c0 < 0
                 f1 = ~f0 & (c1 < 0)
                 f2 = ~f0 & ~f1 & (c2 < 0)
+                if has_window:
+                    # windows keep more cancels pending: one that finds
+                    # no free slot is reported as an overflow
+                    tp_ovf = tp_ovf | (can & ~(f0 | f1 | f2))
                 cancels = (
                     jnp.where(can & f0, ep_fctr, c0),
                     jnp.where(can & f1, ep_fctr, c1),
@@ -911,11 +1089,25 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             # one walk: refill the popped stream, then skip, for the next
             # iteration, every prediction whose action point has passed
             # (nothing reads the cursors before that iteration's na)
-            (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time), n = walk(
-                use_tp, pop & ~use_tp,
-                (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time),
-                phase == B._PH_MAIN, t,
-            )
+            if has_window:
+                buf = buf_pop(buf, use_tp)
+                tp_n = tp_n - use_tp.astype(tp_n.dtype)
+                (la_ctr, la_time, buf, tp_n, fp_ctr, fp_time, tp_ovt,
+                 tp_peak, tp_ovf), n = walk(
+                    use_tp, pop & ~use_tp,
+                    (la_ctr, la_time, buf, tp_n, fp_ctr, fp_time, tp_ovt,
+                     tp_peak, tp_ovf),
+                    phase == B._PH_MAIN, t,
+                )
+            else:
+                (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time), n = (
+                    walk(
+                        use_tp, pop & ~use_tp,
+                        (la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr,
+                         fp_time),
+                        phase == B._PH_MAIN, t,
+                    )
+                )
             nw = nw + n
         else:
             def _pop(args):
@@ -972,9 +1164,13 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
             st.update(
                 sf_ctr=sf_ctr, sf_time=sf_time,
                 la_ctr=la_ctr, la_time=la_time,
-                tp_t0=tp_t0, tp_ft=tp_ft, tp_ctr=tp_ctr,
                 fp_ctr=fp_ctr, fp_time=fp_time,
             )
+            if has_window:
+                st.update(tp_buf=buf, tp_n=tp_n, tp_ovt=tp_ovt,
+                          tp_peak=tp_peak, tp_ovf=tp_ovf)
+            else:
+                st.update(tp_t0=tp_t0, tp_ft=tp_ft, tp_ctr=tp_ctr)
             if has_migration:
                 st["ep_ft"] = ep_ft
                 st["ep_fctr"] = ep_fctr
@@ -1004,17 +1200,31 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
         sf_ctr, sf_time = adv_fault(live, neg1, zf)
         pvis = live & (q_eff > 0.0)
         fp_act = pvis & jnp.isfinite(fp_mean)
-        cur = (neg1, zf, jnp.full_like(horizon, jnp.inf),
-               jnp.full_like(horizon, jnp.nan), neg1,
-               neg1, jnp.where(fp_act, zf, inf))
+        if has_window:
+            # an empty buffer, and zeroed overtake / peak counts
+            zi = jnp.zeros_like(neg1)
+            cur = (neg1, zf, tuple(
+                jnp.full((tp_slots,) + zf.shape, f, d)
+                for f, d in zip(buf_fill, (zf.dtype, zf.dtype, neg1.dtype))
+            ), zi, neg1, jnp.where(fp_act, zf, inf), zi, zi, no_lane)
+        else:
+            cur = (neg1, zf, jnp.full_like(horizon, jnp.inf),
+                   jnp.full_like(horizon, jnp.nan), neg1,
+                   neg1, jnp.where(fp_act, zf, inf))
         cur, _ = walk(pvis & (recall > 0.0), no_lane, cur, no_lane,
                       state["t"])
         cur, _ = walk(no_lane, fp_act, cur, state["phase"] == B._PH_MAIN,
                       state["t"])
-        la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time = cur
+        if has_window:
+            (la_ctr, la_time, buf, tp_n, fp_ctr, fp_time, tp_ovt, tp_peak,
+             tp_ovf) = cur
+            state.update(tp_buf=buf, tp_n=tp_n, tp_ovt=tp_ovt,
+                         tp_peak=tp_peak, tp_ovf=tp_ovf)
+        else:
+            la_ctr, la_time, tp_t0, tp_ft, tp_ctr, fp_ctr, fp_time = cur
+            state.update(tp_t0=tp_t0, tp_ft=tp_ft, tp_ctr=tp_ctr)
         state.update(
             sf_ctr=sf_ctr, sf_time=sf_time, la_ctr=la_ctr, la_time=la_time,
-            tp_t0=tp_t0, tp_ft=tp_ft, tp_ctr=tp_ctr,
             fp_ctr=fp_ctr, fp_time=fp_time,
         )
         if has_migration:
@@ -1045,9 +1255,17 @@ def _jit_run(consts, state, *, use_pallas, interpret, max_iters, eps,
         cond, step, (jnp.int32(0), jnp.int32(0), state)
     )
     # the outer loop's iteration count and the walk's passes ride out
-    # beside the results
+    # beside the results; with windows also the buffer's counts: TPs that
+    # overtook a pending one, the most any lane held, lanes it overflowed
     final = dict(final)
-    final["_counts"] = jnp.stack([n_it, n_walk])
+    counts = [n_it, n_walk]
+    if has_window:
+        counts += [
+            jnp.sum(final["tp_ovt"], dtype=jnp.int32),
+            jnp.max(final["tp_peak"]).astype(jnp.int32),
+            jnp.sum(final["tp_ovf"], dtype=jnp.int32),
+        ]
+    final["_counts"] = jnp.stack(counts)
     if n_seg:
         # per-cell segment reduction on device: one (n_seg, 13) matrix of
         # Monte-Carlo sums per chunk instead of O(lanes) result fetches.
@@ -1209,13 +1427,14 @@ class _ShardedRunner:
             cspec = {k: self._pspec(k) for k in consts}
             sspec = {k: self._pspec(k) for k in state}
             step = self._step
-            # each device's (outer-loop count, walk passes) leaves as one
-            # row of ``_counts``: (n_dev, 2) after the gather
+            # each device's counts (outer-loop count, walk passes, and
+            # with windows the buffer's) leave as one row of ``_counts``:
+            # (n_dev, n_counts) after the gather
             if self._stats:
                 def body(c, s, a):
                     final = step(c, s)
                     cs = jax.lax.psum(final["cell_sums"], "lanes")
-                    return _fold(a, cs), final["_counts"].reshape(1, 2)
+                    return _fold(a, cs), final["_counts"][None]
 
                 fn = jax.jit(
                     jax.shard_map(
@@ -1230,7 +1449,7 @@ class _ShardedRunner:
                 def body(c, s):
                     final = step(c, s)
                     out = {k: final[k] for k in _OUT_KEYS}
-                    out["_counts"] = final["_counts"].reshape(1, 2)
+                    out["_counts"] = final["_counts"][None]
                     return out
 
                 fn = jax.jit(
@@ -1263,12 +1482,16 @@ def _get_runner(
     use_pallas: bool, interpret: bool, max_iters: int, eps: float,
     has_migration: bool, devs, gen=None, gathered=(), n_seg=0,
     stats=False, has_two_level: bool = False, has_silent: bool = False,
+    has_window: bool = False,
 ):
     import jax
 
+    # a call with no window keeps one pending-TP slot: the exact-date
+    # program
+    tp_slots = WINDOW_TP_SLOTS if has_window else 1
     key = (
         use_pallas, interpret, max_iters, eps, has_migration,
-        has_two_level, has_silent,
+        has_two_level, has_silent, tp_slots,
         tuple(d.id for d in devs), gen, gathered, n_seg, stats,
     )
     runner = _RUN_CACHE.get(key)
@@ -1279,6 +1502,7 @@ def _get_runner(
         _jit_run, use_pallas=use_pallas, interpret=interpret,
         max_iters=max_iters, eps=eps, has_migration=has_migration,
         has_two_level=has_two_level, has_silent=has_silent,
+        has_window=has_window, tp_slots=tp_slots,
         gen=gen, gathered=gathered, n_seg=n_seg,
     )
     if len(devs) > 1:
@@ -1716,10 +1940,9 @@ def _acc_init(n_seg: int, fdt, devs):
 
 def _fetch(final, n_real: int):
     """Pull one dispatched chunk's per-lane results back to the host,
-    with its (outer-loop count, walk passes) per device under
-    ``"_counts"``."""
+    with its loop counts per device under ``"_counts"``."""
     out = {k: np.asarray(final[k])[:n_real] for k in _OUT_KEYS}
-    out["_counts"] = np.asarray(final["_counts"]).reshape(-1, 2)
+    out["_counts"] = _count_rows(final["_counts"])
     n_open = int((out.pop("phase") != B._PH_DONE).sum())
     if n_open:
         raise RuntimeError(
@@ -1727,6 +1950,14 @@ def _fetch(final, n_real: int):
             "unfinished at max_iters"
         )
     return out
+
+
+def _count_rows(c) -> np.ndarray:
+    """One chunk's counts as ``(n_devices, _N_COUNTS)`` int64 rows; a
+    call without windows reports none of the buffer's, which read 0."""
+    c = np.asarray(c, np.int64)
+    c = c.reshape(-1, c.shape[-1])
+    return np.pad(c, ((0, 0), (0, _N_COUNTS - c.shape[1])))
 
 
 def _collect(timings: dict, final, n_real: int):
@@ -1984,6 +2215,10 @@ def simulate_batch_jax(
     # other sweep's compiled step (and its packed payload), like migration
     any_tl = bool((mode == B._M_TWO_LEVEL).any())
     any_sil = bool((mode == B._M_SILENT).any())
+    # device traces keep pending TPs in a window-ordered buffer where a
+    # window lets a later fault's prediction open first; one program for
+    # the whole grid, so a law-indexed grid stays one executable
+    any_win = is_spec and bool((traces.window > 0.0).any())
     tl_extra = (C2, R2, fmem, rho) if any_tl else None
     sil_extra = (V, kv) if any_sil else None
     if L == 0:
@@ -2084,6 +2319,7 @@ def simulate_batch_jax(
                 use_pallas, interpret, max_iters, float(_EPS), has_mig,
                 devs, gen, gathered, n_seg, stats=not want_lanes,
                 has_two_level=has_tl, has_silent=has_sil,
+                has_window=any_win,
             )
             with span("repro.engine.pack", timings, "pack_s", chunk=k):
                 if spec_celled:
@@ -2139,14 +2375,24 @@ def simulate_batch_jax(
                 # fetched with every chunk's loop counts
                 cs, counts = jax.device_get((acc, counts))  # repro-lint: disable=host-sync
             cs = np.asarray(cs, np.float64)
-    counts = np.asarray(counts, np.int64).reshape(n_chunks, -1, 2)
+    counts = np.stack([_count_rows(c) for c in counts])
     LAST_TIMINGS.clear()
     LAST_TIMINGS.update(
         trace_mode="device" if is_spec else "host", n_chunks=n_chunks,
         loop_iters=counts[..., 0], walk_passes=counts[..., 1],
+        tp_overtakes=counts[..., 2], tp_pending_peak=counts[..., 3],
+        tp_overflow=counts[..., 4],
         precision=precision, **timings,
         pallas=("interpret" if interpret else "compiled") if use_pallas else "off",
     )
+    n_ovf = int(counts[..., 4].sum())
+    if n_ovf:
+        raise RuntimeError(
+            f"jax batch simulator: {n_ovf} lane(s) had more than "
+            f"{WINDOW_TP_SLOTS} true-positive predictions, or more than 3 "
+            "migration cancellations, pending at once, so their "
+            "predictions could not be served exactly"
+        )
     if not want_lanes:
         n_open = int(cs[:n_cells, _CS_NOTDONE].sum())
         if n_open:

@@ -9,6 +9,7 @@ dispatch (the per-cell waste acceptance gate), and a hypothesis property
 test randomizing platforms, laws, and strategies.
 """
 
+import dataclasses
 import math
 
 import numpy as np
@@ -21,6 +22,7 @@ from repro.core import (
     simulate_batch,
 )
 from repro.core import events as E
+from repro.core import jax_sim
 from repro.core import simulator as S
 from repro.core.jax_sim import simulate_batch_jax
 from repro.core.simulator import Strategy, simulate
@@ -450,21 +452,180 @@ def test_device_gen_matches_host_engines_exact(dist):
         np.testing.assert_array_equal(bj.n_migrations, bn.n_migrations)
 
 
+def _agree_per_lane(bj, bn, context):
+    np.testing.assert_allclose(
+        bj.makespan, bn.makespan, rtol=1e-12, atol=1e-6, err_msg=context,
+    )
+    np.testing.assert_array_equal(bj.n_faults, bn.n_faults)
+    np.testing.assert_array_equal(bj.n_regular_ckpts, bn.n_regular_ckpts)
+    np.testing.assert_array_equal(bj.n_proactive_ckpts, bn.n_proactive_ckpts)
+    np.testing.assert_array_equal(bj.n_migrations, bn.n_migrations)
+
+
 def test_device_gen_window_statistical():
-    """Prediction windows: the device cursor consumes true positives in
-    fault order while the host replay time-sorts them, so individual
-    makespans may differ where two windows overlap — but only at the
-    episode scale (<< makespan), and the waste means agree tightly."""
+    """Prediction windows: the device engine keeps each lane's pending
+    true positives in window-start order, the order of the host replay's
+    time sort, so every lane agrees with the NumPy engine on the
+    materialized streams to float rounding, as exact-date predictions
+    do — lanes where a later fault's window opens first included."""
     for strat in (S.instant(PLAT, PREDW), S.nockpt(PLAT, PREDW),
                   S.withckpt(PLAT, PREDW)):
-        spec = _spec_for(strat, PREDW, E.exponential(), n=6, seed=13)
+        spec = _spec_for(strat, PREDW, E.exponential(), n=64, seed=13)
         bn = simulate_batch(WORK, PLAT, strat, spec.materialize())
         bj = simulate_batch_jax(WORK, PLAT, strat, spec)
-        np.testing.assert_allclose(
-            bj.makespan, bn.makespan, rtol=5e-3, err_msg=strat.name
-        )
-        assert abs(bj.waste.mean() - bn.waste.mean()) < 1e-3, strat.name
-        np.testing.assert_array_equal(bj.n_faults, bn.n_faults)
+        assert jax_sim.LAST_TIMINGS["tp_overtakes"].sum() > 0, strat.name
+        _agree_per_lane(bj, bn, strat.name)
+
+
+@pytest.mark.parametrize(
+    "dist", [E.weibull(0.7), E.lognormal(1.0)], ids=["weibull0.7", "lognormal"]
+)
+def test_device_gen_window_matches_host_replay_per_lane(dist):
+    """Windows wider than the mean fault gap, so pending TPs pile up and
+    overtake often, under the non-exponential laws too, and (at a third
+    of that width) migration's cancel slots set out of fault order: still
+    float-rounding agreement with the host replay, lane by lane."""
+    wide = PredictorModel(recall=0.85, precision=0.6, window=1.5 * PLAT.mu,
+                          lead=3600.0)
+    mig = dataclasses.replace(wide, window=0.5 * PLAT.mu)
+    for strat, pred in ((S.instant(PLAT, wide), wide),
+                        (S.nockpt(PLAT, wide), wide),
+                        (S.withckpt(PLAT, wide), wide),
+                        (S.migration(PLAT, mig), mig)):
+        spec = _spec_for(strat, pred, dist, n=24, seed=7)
+        bn = simulate_batch(WORK, PLAT, strat, spec.materialize())
+        bj = simulate_batch_jax(WORK, PLAT, strat, spec)
+        t = jax_sim.LAST_TIMINGS
+        assert t["tp_overtakes"].sum() > 0 and t["tp_pending_peak"].max() > 1
+        assert t["tp_overflow"].sum() == 0
+        _agree_per_lane(bj, bn, f"{strat.name}/{dist.name}")
+
+
+def _fault_ordered(tr):
+    """The host replay ``tr`` with each lane's true positives served in
+    fault order instead (a one-slot cursor's order), merged with the
+    false predictions by window start, ties to the TP."""
+    t0, ft = tr.pred_t0.copy(), tr.pred_fault.copy()
+    for i in range(tr.n_lanes):
+        n = int(tr.n_preds[i])
+        tp = [(a, b) for a, b in zip(t0[i, :n], ft[i, :n]) if not np.isnan(b)]
+        fp = [(a, b) for a, b in zip(t0[i, :n], ft[i, :n]) if np.isnan(b)]
+        tp.sort(key=lambda e: e[1])
+        out = []
+        while tp or fp:
+            take_tp = tp and (not fp or tp[0][0] <= fp[0][0])
+            out.append((tp if take_tp else fp).pop(0))
+        t0[i, :n] = [e[0] for e in out]
+        ft[i, :n] = [e[1] for e in out]
+    return dataclasses.replace(tr, pred_t0=t0, pred_fault=ft)
+
+
+def test_device_gen_window_serves_a_later_faults_earlier_window_first():
+    """A seeded lane set in which later faults' windows open before
+    earlier ones': the device engine counts those overtakes and serves
+    the predictions in window-start order — its lanes are the replay's
+    in that order, and some differ from the replay in fault order, the
+    order a single pending slot would serve."""
+    wide = PredictorModel(recall=0.9, precision=0.8, window=2.0 * PLAT.mu,
+                          lead=3600.0)
+    strat = S.nockpt(PLAT, wide)
+    spec = _spec_for(strat, wide, E.exponential(), n=16, seed=31)
+    tr = spec.materialize()
+
+    def inverted(i):
+        # TPs in the replay's window-start order, fault dates falling
+        ft = tr.pred_fault[i, :tr.n_preds[i]]
+        return (np.diff(ft[~np.isnan(ft)]) < 0).any()
+
+    assert any(inverted(i) for i in range(tr.n_lanes))
+    bj = simulate_batch_jax(WORK, PLAT, strat, spec)
+    assert jax_sim.LAST_TIMINGS["tp_overtakes"].sum() >= 1
+    _agree_per_lane(bj, simulate_batch(WORK, PLAT, strat, tr), "window order")
+    old = simulate_batch(WORK, PLAT, strat, _fault_ordered(tr))
+    assert (np.abs(old.makespan - bj.makespan) > 1e-3).any()
+
+
+def _captured_step(spec, strat):
+    """The step program and packed chunk of a one-chunk lanes call."""
+    got = []
+
+    class Captured(Exception):
+        pass
+
+    def spy(runner, devs, consts, state, *acc):
+        got.append((runner, consts, state))
+        raise Captured
+
+    orig = jax_sim._dispatch
+    jax_sim._dispatch = spy
+    try:
+        with pytest.raises(Captured):
+            simulate_batch_jax(WORK, PLAT, strat, spec, chunk=None)
+    finally:
+        jax_sim._dispatch = orig
+    return got[0]
+
+
+def test_no_window_keeps_one_pending_slot():
+    """A chunk with no window compiles the one-slot program: its lanes
+    carry one TP slot and no buffer, and it reports no overtakes and no
+    buffer peak though it predicts; a chunk with windows carries
+    ``WINDOW_TP_SLOTS`` slots a lane."""
+    import jax
+
+    exact = S.exact_prediction(PLAT, PRED)
+    spec = _spec_for(exact, PRED, E.exponential(), n=8, seed=3)
+    runner, consts, state = _captured_step(spec, exact)
+    out = jax.eval_shape(runner, consts, state)
+    n_pad = state["t"].shape[0]
+    assert "tp_buf" not in out and out["tp_t0"].shape == (n_pad,)
+    simulate_batch_jax(WORK, PLAT, exact, spec)
+    t = jax_sim.LAST_TIMINGS
+    assert t["walk_passes"].sum() > 0
+    for k in ("tp_overtakes", "tp_pending_peak", "tp_overflow"):
+        assert t[k].shape == (1, 1) and t[k].dtype == np.int64
+        assert (t[k] == 0).all(), k
+
+    inst = S.instant(PLAT, PREDW)
+    spec = _spec_for(inst, PREDW, E.exponential(), n=8, seed=3)
+    runner, consts, state = _captured_step(spec, inst)
+    out = jax.eval_shape(runner, consts, state)
+    assert "tp_t0" not in out
+    assert out["tp_buf"][0].shape == (jax_sim.WINDOW_TP_SLOTS, n_pad)
+
+
+def test_buffer_overflow_is_reported_not_dropped(monkeypatch):
+    """A lane that needs more pending slots than the buffer has is
+    reported — the count ``tp_overflow`` and an error — never served out
+    of window order; the default buffer holds the same lanes."""
+    wide = PredictorModel(recall=0.9, precision=0.8, window=2.0 * PLAT.mu,
+                          lead=3600.0)
+    strat = S.nockpt(PLAT, wide)
+    spec = _spec_for(strat, wide, E.exponential(), n=8, seed=5)
+    monkeypatch.setattr(jax_sim, "WINDOW_TP_SLOTS", 2)
+    with pytest.raises(RuntimeError, match="pending at once"):
+        simulate_batch_jax(WORK, PLAT, strat, spec)
+    t = jax_sim.LAST_TIMINGS
+    assert t["tp_overflow"].sum() > 0 and t["tp_pending_peak"].max() == 2
+    monkeypatch.undo()
+    bj = simulate_batch_jax(WORK, PLAT, strat, spec)
+    t = jax_sim.LAST_TIMINGS
+    assert t["tp_overflow"].sum() == 0 and t["tp_pending_peak"].max() > 2
+    _agree_per_lane(bj, simulate_batch(WORK, PLAT, strat, spec.materialize()),
+                    "default buffer")
+
+
+def test_migration_cancel_overflow_is_reported():
+    """Windows as wide as two fault gaps keep more than three migration
+    cancellations pending in some lane: reported, never dropped."""
+    wide = PredictorModel(recall=0.9, precision=0.8, window=2.0 * PLAT.mu,
+                          lead=3600.0)
+    strat = S.migration(PLAT, wide)
+    spec = _spec_for(strat, wide, E.weibull(0.7), n=24, seed=7)
+    with pytest.raises(RuntimeError, match="migration cancellations"):
+        simulate_batch_jax(WORK, PLAT, strat, spec)
+    assert jax_sim.LAST_TIMINGS["tp_overflow"].sum() > 0
+    assert jax_sim.LAST_TIMINGS["tp_pending_peak"].max() <= jax_sim.WINDOW_TP_SLOTS
 
 
 def test_device_gen_chunk_invariance():

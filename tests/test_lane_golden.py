@@ -14,9 +14,10 @@ specialisation of the prediction walk runs: one law for faults and false
 predictions, two static laws, a mixed-law table against a static law, and
 two mixed-law tables.
 
-Regenerate the fixture only when results are meant to change::
+Regenerate the fixture only when results are meant to change, and only
+the cases meant to change (named cases are rewritten, the others kept)::
 
-    PYTHONPATH=src JAX_PLATFORMS=cpu python tests/test_lane_golden.py
+    PYTHONPATH=src JAX_PLATFORMS=cpu python tests/test_lane_golden.py [case ...]
 """
 
 import os
@@ -182,11 +183,18 @@ def test_the_fixture_covers_every_case():
 
 
 if __name__ == "__main__":
-    arrays = {
+    import sys
+
+    cases = sys.argv[1:] or list(CASES)
+    arrays = {}
+    if sys.argv[1:]:
+        with np.load(FIXTURE) as z:
+            arrays.update(z)
+    arrays.update({
         _key(c, p, k, f): v
-        for c in CASES for p in PRECISIONS for k, chunk in CHUNKS.items()
+        for c in cases for p in PRECISIONS for k, chunk in CHUNKS.items()
         for f, v in _run(c, p, chunk).items()
-    }
+    })
     os.makedirs(os.path.dirname(FIXTURE), exist_ok=True)
     np.savez_compressed(FIXTURE, **arrays)
     print(f"wrote {len(arrays)} arrays to {FIXTURE}")

@@ -1,9 +1,9 @@
 """The program's own spans, kernel scope and loop counters
-(``repro.core.spans``, ``jax_sim.LAST_TIMINGS["loop_iters"]`` and
-``["walk_passes"]``), on the CPU: they leave results bit-identical, keep
-``LAST_TIMINGS``'s fields, count each chunk's outer loop and prediction
-walk, reach a recorded trace nested and tagged as documented, and add no
-host transfer."""
+(``repro.core.spans``, ``jax_sim.LAST_TIMINGS["loop_iters"]``,
+``["walk_passes"]`` and the pending-TP buffer's counts), on the CPU: they
+leave results bit-identical, keep ``LAST_TIMINGS``'s fields, count each
+chunk's outer loop, prediction walk and buffer, reach a recorded trace
+nested and tagged as documented, and add no host transfer."""
 
 import glob
 import os
@@ -49,7 +49,11 @@ SPANS = {
 }
 
 TIMING_KEYS = {"trace_mode", "pack_s", "dispatch_s", "fetch_s", "n_chunks",
-               "loop_iters", "walk_passes", "precision", "pallas"}
+               "loop_iters", "walk_passes", "tp_overtakes", "tp_pending_peak",
+               "tp_overflow", "precision", "pallas"}
+#: the counts of a chunk, one per device, each as ``LAST_TIMINGS`` keeps it
+COUNTS = ("loop_iters", "walk_passes", "tp_overtakes", "tp_pending_peak",
+          "tp_overflow")
 
 
 def _cells(n_runs):
@@ -142,10 +146,12 @@ def test_timings_keep_their_fields_and_meaning():
     t = jax_sim.LAST_TIMINGS
     assert set(t) == TIMING_KEYS
     assert t["n_chunks"] == 2  # 120 lanes in chunks of 64
-    for k in ("loop_iters", "walk_passes"):
+    for k in COUNTS:
         assert t[k].shape == (2, 1)
         assert t[k].dtype == np.int64
-        assert (t[k] > 0).all()
+        # no window in this grid: nothing for the buffer to count
+        assert (t[k] > 0).all() if k in ("loop_iters", "walk_passes") else (
+            t[k] == 0).all()
     for k in ("pack_s", "dispatch_s", "fetch_s"):
         assert t[k] > 0.0
     assert (t["trace_mode"], t["precision"]) == ("device", "x64")
@@ -154,14 +160,18 @@ def test_timings_keep_their_fields_and_meaning():
 @pytest.mark.parametrize("collect", ["stats", "lanes"])
 def test_loop_iters_are_each_chunks_count(collect):
     """``loop_iters[k]`` is chunk ``k``'s outer-loop count, and
-    ``walk_passes[k]`` its prediction walk's passes: lanes evolve
-    independently, so the same lanes run alone take as many of each."""
+    ``walk_passes[k]`` its prediction walk's passes, and the buffer's
+    counts its own (the window cell's chunk overtakes): lanes evolve
+    independently, so the same lanes run alone count as much of each —
+    but for the buffer's peak, which only a call with a window counts
+    (lanes with exact dates hold at most one TP)."""
     plats, strats, cidx, spec = _cells(CHUNK)
     simulate_batch_jax([WORK] * 3, plats, strats, spec, chunk=CHUNK,
                        collect=collect, use_pallas=False)
-    whole = jax_sim.LAST_TIMINGS["loop_iters"]
-    walk = jax_sim.LAST_TIMINGS["walk_passes"]
-    assert whole.shape == walk.shape == (N_CHUNKS, 1)
+    whole = {k: jax_sim.LAST_TIMINGS[k] for k in COUNTS}
+    assert all(v.shape == (N_CHUNKS, 1) for v in whole.values())
+    assert whole["tp_overtakes"][0, 0] > 0
+    assert (whole["tp_overtakes"][1:] == 0).all()
     lane = spec.expand()
     for k in range(N_CHUNKS):
         rows = np.arange(k * CHUNK, (k + 1) * CHUNK)
@@ -170,8 +180,12 @@ def test_loop_iters_are_each_chunks_count(collect):
             [strats[c] for c in cidx[rows]], lane.take(rows), chunk=CHUNK,
             use_pallas=False,
         )
-        assert jax_sim.LAST_TIMINGS["loop_iters"].tolist() == [[whole[k, 0]]]
-        assert jax_sim.LAST_TIMINGS["walk_passes"].tolist() == [[walk[k, 0]]]
+        for name, v in whole.items():
+            got = jax_sim.LAST_TIMINGS[name].tolist()
+            if name == "tp_pending_peak" and k:
+                assert got == [[0]] and v[k, 0] <= 1
+            else:
+                assert got == [[v[k, 0]]], name
 
 
 def _walk_passes(strat, pred, trace_mode="device"):
@@ -223,9 +237,8 @@ half = T.CHUNK // 2
 for collect in ("stats", "lanes"):
     simulate_batch_jax([T.WORK] * 3, plats, strats, spec, chunk=T.CHUNK,
                        devices=2, collect=collect, use_pallas=False)
-    two = jax_sim.LAST_TIMINGS["loop_iters"]
-    walk = jax_sim.LAST_TIMINGS["walk_passes"]
-    assert two.shape == walk.shape == (T.N_CHUNKS, 2), (two.shape, walk.shape)
+    two = {k: jax_sim.LAST_TIMINGS[k] for k in T.COUNTS}
+    assert all(v.shape == (T.N_CHUNKS, 2) for v in two.values()), two
     for k in range(T.N_CHUNKS):
         for d in range(2):
             rows = np.arange(k * T.CHUNK + d * half, k * T.CHUNK + (d + 1) * half)
@@ -234,18 +247,20 @@ for collect in ("stats", "lanes"):
                 [strats[c] for c in cidx[rows]], lane.take(rows),
                 chunk=T.CHUNK, devices=1, use_pallas=False,
             )
-            one = jax_sim.LAST_TIMINGS["loop_iters"]
-            assert one.tolist() == [[two[k, d]]], (collect, k, d, one, two)
-            one = jax_sim.LAST_TIMINGS["walk_passes"]
-            assert one.tolist() == [[walk[k, d]]], (collect, k, d, one, walk)
+            for name, v in two.items():
+                one = jax_sim.LAST_TIMINGS[name].tolist()
+                if name == "tp_pending_peak" and k:  # no window: no buffer
+                    assert one == [[0]] and v[k, d] <= 1, (collect, k, d, v)
+                else:
+                    assert one == [[v[k, d]]], (collect, name, k, d, one, v)
 print("LOOP_ITERS_OK")
 """
 
 
 def test_loop_iters_count_per_device_when_sharded():
     """With 2 forced host devices each chunk reports one count (of loop
-    iterations and of walk passes) per device: that of its shard's lanes
-    run alone on one device."""
+    iterations, of walk passes and of the buffer's) per device: that of
+    its shard's lanes run alone on one device."""
     here = os.path.dirname(os.path.abspath(__file__))
     env = dict(
         os.environ, JAX_PLATFORMS="cpu",
